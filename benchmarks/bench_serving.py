@@ -444,13 +444,15 @@ def _flood_trial(client, sessions: int) -> Dict:
 
 
 def _concurrency_section(smoke: bool) -> Dict:
+    from repro.configs import get_config, reduced
     from repro.core import Orchestrator, TaskRequest
     from repro.gateway import ControlPlaneClient, ControlPlaneGateway
     from repro.substrates import LmServingAdapter
 
     sessions = 16 if smoke else SESSIONS
     orch = Orchestrator(plane="serving-bench")
-    adapter = LmServingAdapter(batch_size=BATCH, max_seq=MAX_SEQ,
+    adapter = LmServingAdapter(reduced(get_config(ARCH)), batch_size=BATCH,
+                               max_seq=MAX_SEQ,
                                max_concurrent=max(sessions, 256))
     orch.register(adapter)
     gw = ControlPlaneGateway(orch, plane="serving-bench",

@@ -75,6 +75,9 @@ def main() -> None:
     ap.add_argument("--only", default=None, choices=sorted(BENCHES))
     args = ap.parse_args()
 
+    from repro import compile_cache
+    compile_cache.enable()
+
     from repro.substrates.http_fast import FastService
     svc = FastService().start()
     print("name,us_per_call,derived")

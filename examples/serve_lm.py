@@ -18,6 +18,7 @@ sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "src"))
 
 import numpy as np
 
+from repro import compile_cache
 from repro.configs import get_config, reduced
 from repro.serving import Request, ServingEngine
 
@@ -50,6 +51,7 @@ def main():
     ap.add_argument("--tokens", type=int, default=12)
     args = ap.parse_args()
 
+    compile_cache.enable()
     cfg = reduced(get_config(args.arch))
     print(f"serving {cfg.name} (reduced config, family={cfg.family})")
     n_reqs = args.batch * 2

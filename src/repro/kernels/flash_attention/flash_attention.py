@@ -81,10 +81,11 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 @functools.partial(jax.jit, static_argnames=("causal", "block_q", "block_k",
                                              "interpret"))
 def flash_attention(q, k, v, *, causal: bool = True, block_q: int = 128,
-                    block_k: int = 128, interpret: bool = True):
+                    block_k: int = 128, interpret: bool):
     """q: (B, H, S, hd); k, v: (B, K, T, hd) with H = K·G. Returns (B,H,S,hd).
 
-    ``interpret=True`` executes on CPU for validation; on TPU pass False.
+    ``interpret=True`` executes the kernel body off the TPU (validation);
+    callers on the model path pass ``repro.kernels.interpret_mode()``.
     """
     B, H, S, hd = q.shape
     K, T = k.shape[1], k.shape[2]

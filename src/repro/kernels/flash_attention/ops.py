@@ -1,7 +1,7 @@
 """Jitted public wrapper: model-layout (B,S,H,hd) → kernel layout and back.
 
 ``use_pallas`` on an ArchConfig routes ``repro.models.attention`` through
-this op on TPU; the pure-JAX chunked path remains the CPU/dry-run default.
+this op; the pure-JAX chunked path is the default and the test reference.
 """
 from __future__ import annotations
 
@@ -23,7 +23,7 @@ def _diff_op(causal, block_q, block_k, interpret):
 
 
 def mha(q, k, v, *, causal: bool = True, block_q: int = 128,
-        block_k: int = 128, interpret: bool = True):
+        block_k: int = 128, interpret: bool):
     """q: (B, S, H, hd); k, v: (B, T, K, hd). Returns (B, S, H, hd).
 
     Differentiable: Pallas kernel forward, oracle-recompute backward."""

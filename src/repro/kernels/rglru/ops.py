@@ -17,7 +17,7 @@ def _diff_op(chunk, block_w, interpret):
 
 
 def linear_recurrence(a, b, *, chunk: int = 64, block_w: int = 128,
-                      interpret: bool = True):
+                      interpret: bool):
     """Differentiable: Pallas kernel forward, oracle backward."""
     return _diff_op(chunk, block_w, interpret)(a, b)
 

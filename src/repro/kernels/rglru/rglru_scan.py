@@ -6,8 +6,8 @@ h_t = a_t ⊙ h_{t-1} + b_t over the time axis, channel-blocked:
   carried state h ∈ R^{wb} (fp32) in VMEM scratch,
 - the channel dimension is blocked to the 128-lane VPU width (this is a
   VPU kernel, not an MXU one — elementwise FMA over lanes),
-- within a chunk the recurrence is an in-register ``fori_loop`` over C
-  timesteps with a dynamic row store per step.
+- within a chunk the recurrence is a ``fori_loop`` over C timesteps that
+  loads and stores one row of the refs per step.
 """
 from __future__ import annotations
 
@@ -26,22 +26,21 @@ def _rglru_kernel(a_ref, b_ref, h_ref, state_scr, *, chunk: int):
     def _init():
         state_scr[...] = jnp.zeros_like(state_scr)
 
-    a = a_ref[0].astype(jnp.float32)         # (C, wb)
-    b = b_ref[0].astype(jnp.float32)
-
     def step(t, h):
-        h = a[t] * h + b[t]
-        pl.store(h_ref, (0, pl.dslice(t, 1), slice(None)),
-                 h[None].astype(h_ref.dtype))
+        # one timestep row, read from and written to the refs: indexing a
+        # loaded value by the loop counter does not lower on TPU
+        row = (0, pl.ds(t, 1), slice(None))
+        h = (a_ref[row].astype(jnp.float32) * h
+             + b_ref[row].astype(jnp.float32))
+        h_ref[row] = h.astype(h_ref.dtype)
         return h
 
-    h_final = jax.lax.fori_loop(0, chunk, step, state_scr[...])
-    state_scr[...] = h_final
+    state_scr[...] = jax.lax.fori_loop(0, chunk, step, state_scr[...])
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "block_w", "interpret"))
 def rglru_scan(a, b, *, chunk: int = 64, block_w: int = 128,
-               interpret: bool = True):
+               interpret: bool):
     """a, b: (B, S, W) — returns h: (B, S, W) with h_t = a_t·h_{t-1} + b_t."""
     B, S, W = a.shape
     assert S % chunk == 0, (S, chunk)
@@ -55,6 +54,6 @@ def rglru_scan(a, b, *, chunk: int = 64, block_w: int = 128,
         in_specs=[spec, spec],
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((B, S, W), a.dtype),
-        scratch_shapes=[pltpu.VMEM((wb,), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((1, wb), jnp.float32)],
         interpret=interpret,
     )(a, b)

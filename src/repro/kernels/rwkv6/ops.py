@@ -15,7 +15,7 @@ def _diff_op(chunk, interpret):
         rwkv6_ref)
 
 
-def time_mix_scan(r, k, v, lw, u, *, chunk: int = 32, interpret: bool = True):
+def time_mix_scan(r, k, v, lw, u, *, chunk: int = 32, interpret: bool):
     """Model-layout entry point. r,k,v,lw: (B,S,H,hd); u: (H,hd).
 
     Differentiable: Pallas kernel forward, oracle-recompute backward."""

@@ -34,7 +34,7 @@ from repro.distributed.sharding import RECIPES
 from repro.launch.mesh import make_production_mesh
 from repro.launch.specs import input_specs
 from repro.models import build_decode_step, build_prefill_step, count_params
-from repro.roofline.analysis import HW, model_flops, roofline_terms
+from repro.roofline.analysis import V5E, model_flops, roofline_terms
 from repro.roofline.hlo import analyze
 from repro.training.train_step import build_train_step
 
@@ -122,7 +122,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
 
     flops = la["flops"]
     hbm_bytes = la["bytes"]
-    terms = roofline_terms(flops, hbm_bytes, coll["total"])
+    terms = roofline_terms(flops, hbm_bytes, coll["total"], V5E)
 
     tokens = shape.global_batch * (shape.seq_len if shape.kind != "decode" else 1)
     n_active = count_params(cfg, active_only=True, include_embed=False)
@@ -140,16 +140,16 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
             "alias_bytes": ma.alias_size_in_bytes,
             "peak_live_bytes": ma.argument_size_in_bytes + ma.temp_size_in_bytes
                                + ma.output_size_in_bytes - ma.alias_size_in_bytes,
-            "hbm_budget_bytes": int(HW.hbm_bytes),
+            "hbm_budget_bytes": int(V5E.hbm_bytes),
             "fits": (ma.argument_size_in_bytes + ma.temp_size_in_bytes
                      + ma.output_size_in_bytes - ma.alias_size_in_bytes)
-                    <= HW.hbm_bytes,
+                    <= V5E.hbm_bytes,
             # the CPU PjRt client ignores donate_argnums (alias bytes = 0);
             # on the TPU target the donated state aliases its output, so the
             # realistic criterion discounts the output buffer
             "fits_with_donation": (ma.argument_size_in_bytes
                                    + ma.temp_size_in_bytes
-                                   - ma.alias_size_in_bytes) <= HW.hbm_bytes,
+                                   - ma.alias_size_in_bytes) <= V5E.hbm_bytes,
         },
         "cost": {
             "flops_per_device": flops,

@@ -98,15 +98,16 @@ def _block_attend(q_blk, k, v, row_pos, col_pos, *, causal, window, kv_valid):
 
 
 def pallas_attention(cfg, q, k, v, *, causal: bool):
-    """Route through the Pallas flash kernel (TPU target; interpret on CPU).
+    """Route through the Pallas flash kernel (compiled on a TPU, the
+    interpreter elsewhere — see :func:`repro.kernels.interpret_mode`).
 
     Only sound for from-scratch causal/bidirectional attention without
     windows/offsets — callers gate on that.
     """
+    from repro.kernels import interpret_mode
     from repro.kernels.flash_attention.ops import mha
 
-    interpret = jax.default_backend() != "tpu"
-    return mha(q, k, v, causal=causal, interpret=interpret)
+    return mha(q, k, v, causal=causal, interpret=interpret_mode())
 
 
 def chunked_attention(q, k, v, *, causal: bool = True, window: Optional[int] = None,
@@ -224,7 +225,7 @@ def prefill_attention(cfg, p: dict, x, positions, *, window: Optional[int] = Non
                                  window=window, chunk=cfg.attn_chunk)
     if y is None:
         o = chunked_attention(q, k, v, causal=True, window=window,
-                              chunk=cfg.attn_chunk)
+                              chunk=cfg.attn_chunk, cfg=cfg)
         y = out_proj(p, o)
     if window is not None and k.shape[1] > window:
         k, v = k[:, -window:], v[:, -window:]

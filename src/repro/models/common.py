@@ -50,6 +50,23 @@ def tree_specs(tree):
     return jax.tree.leaves(tree, is_leaf=is_spec)
 
 
+def _fan_in(spec: ParamSpec) -> int:
+    """Inputs each output of the leaf's contraction sums over.  Head-shaped
+    projections contract more than ``shape[-2]``: ``(d, heads, head_dim)``
+    sums over ``d``, ``(heads, head_dim, d)`` over ``heads * head_dim``;
+    scan-stacked ``layers`` and ``expert`` axes contract nothing."""
+    dims = [(n, a) for n, a in zip(spec.shape, spec.axes)
+            if a not in ("layers", "expert")]
+    names = [a for _, a in dims]
+    for head_axis in ("heads", "kv_heads"):
+        if head_axis in names:
+            i = names.index(head_axis)
+            if i == 0:
+                return dims[0][0] * dims[1][0]
+            return math.prod(n for n, _ in dims[:i])
+    return spec.shape[-2] if len(spec.shape) >= 2 else spec.shape[-1]
+
+
 def _init_leaf(key, spec: ParamSpec):
     if spec.init == "zeros":
         return jnp.zeros(spec.shape, spec.dtype)
@@ -59,11 +76,15 @@ def _init_leaf(key, spec: ParamSpec):
         # log-decay init for recurrences: a in (0.9, 0.999)
         u = jax.random.uniform(key, spec.shape, jnp.float32, 0.9, 0.999)
         return jnp.log(-jnp.log(u)).astype(spec.dtype)
-    fan_in = spec.shape[-2] if len(spec.shape) >= 2 else spec.shape[-1]
-    std = spec.scale / math.sqrt(max(fan_in, 1))
+    std = spec.scale / math.sqrt(max(_fan_in(spec), 1))
     if spec.init == "small":
         std = 0.02 * spec.scale
     return (std * jax.random.normal(key, spec.shape, jnp.float32)).astype(spec.dtype)
+
+
+#: one compiled draw per distinct spec; it returns ``spec.dtype``, so a bf16
+#: leaf is built on the device without a float32 copy of the whole leaf
+_draw_leaf = jax.jit(_init_leaf, static_argnums=1)
 
 
 def init_params(specs, seed: int = 0):
@@ -71,7 +92,7 @@ def init_params(specs, seed: int = 0):
     leaves, treedef = jax.tree.flatten(specs, is_leaf=is_spec)
     root = jax.random.PRNGKey(seed)
     keys = jax.random.split(root, len(leaves))
-    vals = [_init_leaf(k, s) for k, s in zip(keys, leaves)]
+    vals = [_draw_leaf(k, s) for k, s in zip(keys, leaves)]
     return jax.tree.unflatten(treedef, vals)
 
 

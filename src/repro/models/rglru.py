@@ -88,11 +88,12 @@ def rglru_block(cfg, p: dict, x, h0=None, conv_state=None):
             and W % min(128, W) == 0):
         # Pallas sequential-scan kernel; the carried state enters as b_0 of
         # a length-S+? recurrence — fold it into b instead: h_1 = a_1·h0 + b_1
+        from repro.kernels import interpret_mode
         from repro.kernels.rglru.ops import linear_recurrence
 
         b_seeded = b.at[:, 0].add(a[:, 0] * h0)
         h = linear_recurrence(a, b_seeded, chunk=64, block_w=min(128, W),
-                              interpret=jax.default_backend() != "tpu")
+                              interpret=interpret_mode())
     else:
         def combine(lhs, rhs):
             a1, b1 = lhs

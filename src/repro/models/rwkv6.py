@@ -149,11 +149,11 @@ def rwkv_time_mix(cfg, p: dict, x, x_prev=None, state=None,
     use_kernel = (cfg.use_pallas and not want_state and S % CHUNK == 0
                   and x_prev is None)
     if use_kernel:
+        from repro.kernels import interpret_mode
         from repro.kernels.rwkv6.ops import time_mix_scan
 
         y = time_mix_scan(r, k, v, lw, p["u"].astype(jnp.float32),
-                          chunk=CHUNK,
-                          interpret=jax.default_backend() != "tpu")
+                          chunk=CHUNK, interpret=interpret_mode())
     else:
         y, state = _chunk_scan(r, k, v, lw, p["u"].astype(jnp.float32), state)
     return _readout(cfg, p, y, g, x.dtype), state, x[:, -1]

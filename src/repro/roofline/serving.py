@@ -32,7 +32,7 @@ import numpy as np
 from repro.models import (decode_cache, decode_cache_paged, model_specs,
                           paged_cache_flags)
 from repro.models.common import param_count
-from repro.roofline.analysis import HW, Hardware, model_flops, roofline_terms
+from repro.roofline.analysis import model_flops, peaks_for, roofline_terms
 
 _DTYPE_BYTES = {"bfloat16": 2, "float16": 2, "float32": 4, "float64": 8}
 
@@ -84,9 +84,10 @@ class ServingCostModel:
     WINDOW = 64
 
     def __init__(self, cfg, *, batch_size: int, max_seq: int,
-                 hw: Hardware = HW, safety: float = SAFETY,
-                 page_size: Optional[int] = None,
+                 safety: float = SAFETY, page_size: Optional[int] = None,
                  pool_pages: Optional[int] = None):
+        # the peaks of the device this engine runs on
+        self.hw = peaks_for()
         self.batch_size = batch_size
         self.max_seq = max_seq
         self.safety = safety
@@ -111,11 +112,11 @@ class ServingCostModel:
         # one decode step of the full batch: 2·N FLOPs per live token, one
         # full parameter read, one KV-cache sweep
         flops = model_flops(n_params, batch_size, kind="inference")
-        self._terms = roofline_terms(flops, pbytes + kv_bytes, 0.0, hw)
+        self._terms = roofline_terms(flops, pbytes + kv_bytes, 0.0, self.hw)
         self.step_lb_ms = self._terms["step_time_lb_s"] * 1e3
         # per-token prefill lower bound: same arithmetic at batch 1, token 1
         pf = roofline_terms(model_flops(n_params, 1, kind="inference"),
-                            pbytes, 0.0, hw)
+                            pbytes, 0.0, self.hw)
         self.prefill_lb_ms_per_token = pf["step_time_lb_s"] * 1e3
         self._lock = threading.Lock()
         self._step_ms: Deque[float] = collections.deque(maxlen=self.WINDOW)
@@ -177,6 +178,7 @@ class ServingCostModel:
             "step_ms": round(self.step_ms(), 4),
             "prefill_lb_ms_per_token": round(self.prefill_lb_ms_per_token, 6),
             "dominant": self._terms["dominant"],
+            "peaks": self.hw.name,
             "observed_steps": n_step,
             "observed_prefills": n_pf,
         }

@@ -31,7 +31,7 @@ from typing import Dict, Optional
 
 import numpy as np
 
-from repro.configs import get_config, reduced
+from repro.configs import ArchConfig
 from repro.core.descriptors import (CapabilityDescriptor, LifecycleSemantics,
                                     Observability, PolicyConstraints,
                                     ResourceDescriptor, SignalSpec,
@@ -106,22 +106,28 @@ class ServingSurrogate(TwinSurrogate):
 
 
 class LmServingAdapter(SubstrateAdapter):
-    """Continuous-batching LM serving engine behind the substrate surface."""
+    """Continuous-batching LM serving engine behind the substrate surface.
 
-    def __init__(self, arch: str = "internlm2-20b", *, batch_size: int = 4,
-                 max_seq: int = 128, seed: int = 0,
+    Serves ``cfg`` as given: a published-width config (e.g.
+    ``repro.configs.internlm2_20b.ONE_CHIP``) or a ``reduced(...)`` one.
+    ``params`` lets several adapters share one set of weights; without it
+    the engine draws them from ``seed``."""
+
+    def __init__(self, cfg: ArchConfig, *, batch_size: int = 4,
+                 max_seq: int = 128, seed: int = 0, params=None,
                  max_concurrent: int = 256, safety: Optional[float] = None,
                  calibrate: bool = True, paged: bool = False,
                  page_size: int = 16, pool_pages: Optional[int] = None,
                  prefix_sharing: bool = True,
                  clock: Optional[Clock] = None):
         super().__init__()
-        self.arch = arch
-        self.resource_id = f"lm-serving-{arch}"
-        self.cfg = reduced(get_config(arch))
+        self.arch = cfg.name
+        self.resource_id = f"lm-serving-{cfg.name}"
+        self.cfg = cfg
         self.batch_size = batch_size
         self.max_seq = max_seq
         self.seed = seed
+        self.params = params
         self.max_concurrent = max_concurrent
         self.calibrate = calibrate
         self.clock = clock if clock is not None else SYSTEM_CLOCK
@@ -220,7 +226,8 @@ class LmServingAdapter(SubstrateAdapter):
         self._check_prepare_fault()
         if self.engine is not None:
             return
-        engine = ServingEngine(self.cfg, batch_size=self.batch_size,
+        engine = ServingEngine(self.cfg, self.params,
+                               batch_size=self.batch_size,
                                max_seq=self.max_seq, seed=self.seed,
                                paged=self.paged, page_size=self.page_size,
                                pool_pages=self.pool_pages,

@@ -1,5 +1,6 @@
 """Pallas kernel validation: shape/dtype sweeps against pure-jnp oracles
-(interpret=True executes the kernel body on CPU; TPU is the target)."""
+(the tests pass interpret=True: the Pallas interpreter runs the kernel body
+on the CPU; the compiled TPU path is checked by tests/test_tpu_compile.py)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -32,7 +33,8 @@ def test_flash_attention_sweep(B, S, H, K, hd, causal, dtype):
     q = jnp.asarray(RNG.normal(size=(B, S, H, hd)), dtype)
     k = jnp.asarray(RNG.normal(size=(B, S, K, hd)), dtype)
     v = jnp.asarray(RNG.normal(size=(B, S, K, hd)), dtype)
-    out = mha(q, k, v, causal=causal, block_q=64, block_k=64)
+    out = mha(q, k, v, causal=causal, block_q=64, block_k=64,
+              interpret=True)
     ref = mha_ref(q, k, v, causal=causal)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32), **_tol(dtype))
@@ -43,7 +45,8 @@ def test_flash_attention_block_shape_invariance(block_q, block_k):
     q = jnp.asarray(RNG.normal(size=(1, 256, 4, 64)), jnp.float32)
     k = jnp.asarray(RNG.normal(size=(1, 256, 2, 64)), jnp.float32)
     v = jnp.asarray(RNG.normal(size=(1, 256, 2, 64)), jnp.float32)
-    out = mha(q, k, v, causal=True, block_q=block_q, block_k=block_k)
+    out = mha(q, k, v, causal=True, block_q=block_q, block_k=block_k,
+              interpret=True)
     ref = mha_ref(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
@@ -63,7 +66,7 @@ def test_rwkv6_kernel_sweep(B, S, H, hd, chunk, dtype):
     # pairwise-exponent formulation is exact for)
     lw = -jnp.asarray(RNG.uniform(0.01, 4.0, size=(B, S, H, hd)), jnp.float32)
     u = jnp.asarray(RNG.normal(size=(H, hd)), jnp.float32)
-    out = time_mix_scan(r, k, v, lw, u, chunk=chunk)
+    out = time_mix_scan(r, k, v, lw, u, chunk=chunk, interpret=True)
     ref = time_mix_ref(r, k, v, lw, u)
     scale = float(jnp.max(jnp.abs(ref.astype(jnp.float32)))) + 1e-6
     err = float(jnp.max(jnp.abs(out.astype(jnp.float32)
@@ -79,7 +82,8 @@ def test_rwkv6_kernel_sweep(B, S, H, hd, chunk, dtype):
 def test_rglru_kernel_sweep(B, S, W, chunk, block_w):
     a = jnp.asarray(RNG.uniform(0.2, 0.999, size=(B, S, W)), jnp.float32)
     b = jnp.asarray(RNG.normal(size=(B, S, W)), jnp.float32)
-    h = linear_recurrence(a, b, chunk=chunk, block_w=block_w)
+    h = linear_recurrence(a, b, chunk=chunk, block_w=block_w,
+                          interpret=True)
     ref = linear_recurrence_ref(a, b)
     np.testing.assert_allclose(np.asarray(h), np.asarray(ref),
                                rtol=1e-4, atol=1e-4)
@@ -93,7 +97,7 @@ def test_rwkv6_state_continuity():
     v = jnp.asarray(RNG.normal(size=(B, S, H, hd)), jnp.float32)
     lw = -jnp.asarray(RNG.uniform(0.05, 1.0, size=(B, S, H, hd)), jnp.float32)
     u = jnp.asarray(RNG.normal(size=(H, hd)), jnp.float32)
-    o32 = time_mix_scan(r, k, v, lw, u, chunk=32)
-    o128 = time_mix_scan(r, k, v, lw, u, chunk=128)
+    o32 = time_mix_scan(r, k, v, lw, u, chunk=32, interpret=True)
+    o128 = time_mix_scan(r, k, v, lw, u, chunk=128, interpret=True)
     np.testing.assert_allclose(np.asarray(o32), np.asarray(o128),
                                rtol=1e-4, atol=1e-4)

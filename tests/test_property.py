@@ -12,7 +12,7 @@ from repro.core.descriptors import shared_key_ratio
 from repro.core.matcher import Matcher
 from repro.core.telemetry import RuntimeSnapshot
 from repro.models.common import rmsnorm, layernorm, rope
-from repro.roofline.analysis import roofline_terms
+from repro.roofline.analysis import PEAKS, roofline_terms
 from repro.substrates import MemristiveAdapter
 
 jax.config.update("jax_platforms", "cpu")
@@ -52,13 +52,14 @@ def test_shared_key_ratio_bounds(dicts):
 @given(flops=st.floats(1e6, 1e18), byts=st.floats(1e3, 1e15),
        coll=st.floats(0, 1e14))
 def test_roofline_terms_invariants(flops, byts, coll):
-    t = roofline_terms(flops, byts, coll)
-    assert t["step_time_lb_s"] == pytest.approx(
-        max(t["compute_s"], t["memory_s"], t["collective_s"]))
-    assert 0.0 <= t["roofline_fraction"] <= 1.0 + 1e-9
-    assert t["dominant"] in ("compute", "memory", "collective")
-    # the dominant term is the bound
-    assert t[t["dominant"] + "_s"] == pytest.approx(t["step_time_lb_s"])
+    for hw in PEAKS.values():
+        t = roofline_terms(flops, byts, coll, hw)
+        assert t["step_time_lb_s"] == pytest.approx(
+            max(t["compute_s"], t["memory_s"], t["collective_s"]))
+        assert 0.0 <= t["roofline_fraction"] <= 1.0 + 1e-9
+        assert t["dominant"] in ("compute", "memory", "collective")
+        # the dominant term is the bound
+        assert t[t["dominant"] + "_s"] == pytest.approx(t["step_time_lb_s"])
 
 
 @settings(max_examples=15, deadline=None)

@@ -214,7 +214,8 @@ def serving_orchestrator():
     from repro.substrates import LmServingAdapter
 
     orch = Orchestrator(plane="serving-test")
-    adapter = LmServingAdapter(batch_size=2, max_seq=MAX_SEQ)
+    adapter = LmServingAdapter(reduced(get_config(ARCH)), batch_size=2,
+                               max_seq=MAX_SEQ)
     orch.register(adapter)
     yield orch, adapter
     adapter.close()
