@@ -86,13 +86,10 @@ class ControlPlaneScheduler:
         self._stats_lock = threading.Lock()
         self._status_counts: Dict[str, int] = {}    # guarded_by: _stats_lock
         self._per_resource: Dict[str, int] = {}     # guarded_by: _stats_lock
-        self._latencies_ms: List[float] = []        # guarded_by: _stats_lock
         # recent completion timestamps: the observed DRAIN RATE for
         # retry_after_s (end-to-end latencies include queue wait, which
         # would inflate a backoff hint exactly when the queue is busy)
         self._done_times: "deque[float]" = deque(maxlen=32)  # guarded_by: _stats_lock
-        self._first_enqueue: Optional[float] = None          # guarded_by: _stats_lock
-        self._last_done: Optional[float] = None              # guarded_by: _stats_lock
 
     # -- lifecycle ------------------------------------------------------------
     def start(self) -> "ControlPlaneScheduler":
@@ -140,7 +137,7 @@ class ControlPlaneScheduler:
             self._space.notify_all()
         if started:
             for _ in range(self.workers):
-                self._queue.put((_STOP, None, None, 0.0))
+                self._queue.put((_STOP, None, None))
             if wait:
                 for t in threads:
                     t.join()
@@ -178,7 +175,6 @@ class ControlPlaneScheduler:
             else self.default_deadline_s
         clock = self.clock
         deadline = (clock.monotonic() + budget) if budget is not None else None
-        enqueued = clock.monotonic()
         # closed-check + enqueue are atomic w.r.t. shutdown(), so a task is
         # either rejected here or is guaranteed to sit ahead of the stop
         # sentinels.  A full queue parks the producer on the _space
@@ -190,7 +186,7 @@ class ControlPlaneScheduler:
                 if self._closed:
                     raise SchedulerClosed("scheduler already shut down")
                 try:
-                    self._queue.put_nowait((task, fut, deadline, enqueued))
+                    self._queue.put_nowait((task, fut, deadline))
                 except queue.Full:
                     clock.wait_for(
                         self._space,
@@ -198,12 +194,6 @@ class ControlPlaneScheduler:
                 else:
                     self._pending += 1
                     break
-        # _first_enqueue belongs to the stats group (read in stats() under
-        # _stats_lock); stamp it AFTER releasing _lock so the two locks are
-        # never nested
-        with self._stats_lock:
-            if self._first_enqueue is None:
-                self._first_enqueue = enqueued
         return fut
 
     def submit_many(self, tasks: Sequence[TaskRequest],
@@ -276,7 +266,7 @@ class ControlPlaneScheduler:
     # -- worker loop ----------------------------------------------------------
     def _worker(self) -> None:
         while True:
-            task, fut, deadline, enqueued = self._queue.get()
+            task, fut, deadline = self._queue.get()
             with self._lock:
                 self._space.notify()       # one queue slot freed
             if task is _STOP:
@@ -295,27 +285,26 @@ class ControlPlaneScheduler:
                             code=ErrorCode.DEADLINE)
                     except BaseException as e:  # noqa: BLE001 — via future
                         fut.set_exception(e)
-                        self._account(None, enqueued)
+                        self._account(None)
                         continue
                     fut.set_result((result, trace))
-                    self._account(result, enqueued)
+                    self._account(result)
                     continue
                 try:
                     result, trace = self.orchestrator.execute(
                         task, deadline=deadline)
                 except BaseException as e:   # noqa: BLE001 — surfaced via future
                     fut.set_exception(e)
-                    self._account(None, enqueued)
+                    self._account(None)
                     continue
                 fut.set_result((result, trace))
-                self._account(result, enqueued)
+                self._account(result)
             finally:
                 with self._idle:
                     self._pending -= 1
                     self._idle.notify_all()
 
-    def _account(self, result: Optional[InvocationResult],
-                 enqueued: float) -> None:
+    def _account(self, result: Optional[InvocationResult]) -> None:
         now = self.clock.monotonic()
         with self._stats_lock:
             status = result.status if result is not None else "error"
@@ -324,37 +313,20 @@ class ControlPlaneScheduler:
             if result is not None and result.resource_id:
                 self._per_resource[result.resource_id] = \
                     self._per_resource.get(result.resource_id, 0) + 1
-            self._latencies_ms.append((now - enqueued) * 1e3)
             self._done_times.append(now)
-            self._last_done = now
 
     # -- observability --------------------------------------------------------
     def stats(self) -> Dict:
-        """Live counters: status mix, per-substrate placement, end-to-end
-        latency percentiles (enqueue → resolve) and observed throughput."""
+        """Live counters: tasks resolved, queued + in flight, status mix and
+        per-substrate placement."""
         with self._stats_lock:
-            lats = sorted(self._latencies_ms)
             counts = dict(self._status_counts)
             per_resource = dict(self._per_resource)
-            first, last = self._first_enqueue, self._last_done
-        done = len(lats)
-        wall_s = (last - first) if (first is not None and last is not None
-                                    and last > first) else None
-
-        def pct(p: float) -> Optional[float]:
-            if not lats:
-                return None
-            return lats[min(done - 1, int(p * (done - 1)))]
-
         return {
-            "done": done,
+            "done": sum(counts.values()),
             "pending": self.pending,
             "statuses": counts,
             "per_resource": per_resource,
-            "p50_ms": pct(0.50),
-            "p95_ms": pct(0.95),
-            "wall_s": wall_s,
-            "tasks_per_s": (done / wall_s) if wall_s else None,
         }
 
     @property

@@ -38,6 +38,19 @@ Per-request serving telemetry (TTFT, decode tokens/s) is stamped on the
 (``clock=`` ctor arg — the PR 8 simulator can drive serving on virtual
 time); the control-plane adapter (``repro.substrates.lm_serving``) forwards
 it to the ``TelemetryBus``.
+
+Each phase of the engine is a named host span (``serving/spans.py``), so a
+profile of the serving process puts every device-idle gap down to what the
+host was doing.  The driver thread opens ``engine.step`` (the whole of
+:meth:`ServingEngine.step`) and inside it ``engine.admit`` (the admissions),
+``engine.prime`` (one admission: page lookup, dispatch, first token ready),
+``engine.prepare`` (tokens, positions, page growth, the page-table upload),
+``engine.decode`` (dispatch to logits ready), ``engine.sample`` (argmax and
+copy to the host) and ``engine.emit`` (tokens appended, rows finished,
+``on_complete``); ``engine.park`` is the idle wait of ``serve_forever``.
+Caller threads open ``engine.submit`` and, inside it, ``engine.submit.lock``
+(the wait for the engine's lock).  The first dispatch of each program shape
+carries ``new_shape=1``.
 """
 from __future__ import annotations
 
@@ -61,6 +74,7 @@ from repro.models.common import init_params
 from repro.serving.cache_utils import (extend_cache, gather_pages,
                                        write_prefill_paged, write_slots)
 from repro.serving.kv_pages import PagePool, PrefixCache
+from repro.serving.spans import span
 
 
 @dataclasses.dataclass
@@ -75,6 +89,8 @@ class Request:
     deadline_s: Optional[float] = None
     #: serving telemetry (engine-clock monotonic stamps, engine-filled)
     arrived_s: Optional[float] = None
+    #: when the request joined the waiting queue (after admission)
+    enqueued_s: Optional[float] = None
     first_token_s: Optional[float] = None
     finished_s: Optional[float] = None
     #: True when the request finished after its deadline (admitted requests
@@ -128,6 +144,18 @@ class ServingEngine:
     ``batch_size × max_seq`` — so a paged engine admits more concurrent
     short requests than it has contiguous rows for, and a single request
     may exceed what one slot-granular row could ever hold.
+
+    ``metrics`` holds running counters, updated under the engine's lock:
+    ``prefill_ms`` and ``decode_ms`` (the ``engine.prime`` and
+    ``engine.decode`` spans), ``decode_steps``, ``tokens``, ``requests``,
+    ``deadline_expired``; ``submits`` (submit calls that took the lock) and
+    ``lock_wait_ms`` (their ``engine.submit.lock``); ``primes`` and
+    ``queue_ms`` (each primed request's wait from ``enqueued_s`` to its
+    prime, on the engine clock); ``host_ms`` (each ``engine.step`` less its
+    primes and decode); ``decode_rows`` (live rows summed over decode
+    steps); ``new_shapes`` (dispatches of a prime length, a (suffix,
+    prefix) pair or a decode width not run before: where a trace or a
+    compile lands).
     """
 
     def __init__(self, cfg, params=None, *, batch_size: int = 2,
@@ -178,7 +206,11 @@ class ServingEngine:
                               jax.jit(build_decode_step(cfg), donate_argnums=1))
         self.metrics: Dict[str, float] = {
             "prefill_ms": 0.0, "decode_ms": 0.0, "decode_steps": 0,
-            "tokens": 0, "requests": 0, "deadline_expired": 0}
+            "tokens": 0, "requests": 0, "deadline_expired": 0,
+            "submits": 0, "lock_wait_ms": 0.0, "primes": 0, "queue_ms": 0.0,
+            "host_ms": 0.0, "decode_rows": 0, "new_shapes": 0}
+        #: program shapes the continuous path has dispatched
+        self._shapes: set = set()
         # continuous-batching state
         self._slots = [_Slot(i) for i in range(batch_size)]
         self._waiting: Deque[Request] = collections.deque()
@@ -275,10 +307,9 @@ class ServingEngine:
             prompts[i, S - len(r.prompt):] = r.prompt     # left-pad
         batch = {"tokens": jnp.asarray(prompts), **self._batch_extras(B)}
 
-        t0 = time.perf_counter()
-        prefill_cache, logits = self._prefill(self.params, batch)
-        logits = jax.block_until_ready(logits)
-        self.metrics["prefill_ms"] += (time.perf_counter() - t0) * 1e3
+        with span("engine.prime", self.metrics, "prefill_ms"):
+            prefill_cache, logits = self._prefill(self.params, batch)
+            logits = jax.block_until_ready(logits)
 
         # decode continues in a max_seq cache primed from the prefill cache
         cache = decode_cache(self.cfg, B, self.max_seq)
@@ -292,10 +323,10 @@ class ServingEngine:
         step = 0
         while any(not r.done for r in requests):
             pos = jnp.int32(S + step)
-            t0 = time.perf_counter()
-            cache, logits = self._decode_dense(self.params, cache, token, pos)
-            logits = jax.block_until_ready(logits)
-            self.metrics["decode_ms"] += (time.perf_counter() - t0) * 1e3
+            with span("engine.decode", self.metrics, "decode_ms"):
+                cache, logits = self._decode_dense(self.params, cache, token,
+                                                   pos)
+                logits = jax.block_until_ready(logits)
             self.metrics["decode_steps"] += 1
             token = jnp.argmax(logits, axis=-1).astype(jnp.int32)[:, None]
             tok_np = np.asarray(token[:, 0])
@@ -319,30 +350,48 @@ class ServingEngine:
         pool cannot hold the request's worst-case need, or whatever the
         admission hook raises (e.g. a roofline-predicted ``DEADLINE``) —
         all without touching engine state."""
-        self._validate(r)
-        if r.arrived_s is None:
-            r.arrived_s = self.clock.monotonic()
-        if self.admission is not None:
-            self.admission(r, self)
-        with self._work:
-            if self._pool is not None:
-                need = self._pages_needed(len(r.prompt) + r.max_new_tokens)
-                if not self._pool.reserve(need):
-                    raise AdmissionRefused(
-                        ErrorCode.QUEUE_SATURATED,
-                        f"{r.request_id}: queue saturated: kv page pool "
-                        f"cannot hold {need} more pages "
-                        f"({self._pool.reserved_pages}/{self._pool.num_pages}"
-                        f" reserved)",
-                        detail={"retry_after_s": self._retry_after_s(),
-                                "needed_pages": need,
-                                "pool_pages": self._pool.num_pages,
-                                "pool_pages_used": self._pool.used_pages(),
-                                "reserved_pages": self._pool.reserved_pages})
-                r.reserved_pages = need
-            self._waiting.append(r)
-            self._work.notify_all()
-        return r
+        with span("engine.submit"):
+            self._validate(r)
+            if r.arrived_s is None:
+                r.arrived_s = self.clock.monotonic()
+            if self.admission is not None:
+                self.admission(r, self)
+            waited = span("engine.submit.lock", self.metrics,
+                          "lock_wait_ms").start()
+            with self._work:
+                waited.stop()
+                self.metrics["submits"] += 1
+                if self._pool is not None:
+                    need = self._pages_needed(
+                        len(r.prompt) + r.max_new_tokens)
+                    if not self._pool.reserve(need):
+                        raise AdmissionRefused(
+                            ErrorCode.QUEUE_SATURATED,
+                            f"{r.request_id}: queue saturated: kv page pool "
+                            f"cannot hold {need} more pages "
+                            f"({self._pool.reserved_pages}/"
+                            f"{self._pool.num_pages} reserved)",
+                            detail={"retry_after_s": self._retry_after_s(),
+                                    "needed_pages": need,
+                                    "pool_pages": self._pool.num_pages,
+                                    "pool_pages_used":
+                                        self._pool.used_pages(),
+                                    "reserved_pages":
+                                        self._pool.reserved_pages})
+                    r.reserved_pages = need
+                r.enqueued_s = self.clock.monotonic()
+                self._waiting.append(r)
+                self._work.notify_all()
+            return r
+
+    def _new_shape(self, shape) -> bool:
+        """Whether the continuous path dispatches ``shape`` for the first
+        time (counted in ``new_shapes``)."""
+        if shape in self._shapes:
+            return False
+        self._shapes.add(shape)
+        self.metrics["new_shapes"] += 1
+        return True
 
     def _pages_needed(self, tokens: int) -> int:
         return -(-tokens // self.page_size)
@@ -476,49 +525,56 @@ class ServingEngine:
                 if self._pool is not None
                 else decode_cache(self.cfg, self.batch_size, self.max_seq))
         slot_arr = jnp.asarray([slot.index], jnp.int32)
-        t0 = time.perf_counter()
-        if self._pool is not None:
-            shared: List[int] = []
-            if self._prefix is not None:
-                _, shared = self._prefix.lookup(prompt, self.page_size)
-            prefix_tokens = len(shared) * self.page_size
-            fresh = self._alloc_pages(self._pages_needed(S) - len(shared))
-            slot.pages = list(shared) + fresh
-            self._tables[slot.index, :] = 0
-            self._tables[slot.index, :len(slot.pages)] = slot.pages
-            self._tables_dev.clear()
-            suffix = prompt[prefix_tokens:]
-            batch = {"tokens": jnp.asarray(suffix[None, :]),
-                     **self._batch_extras(1)}
-            if shared:
-                self._cb_cache, tok = self._prime_past(
-                    self.params, batch, self._cb_cache,
-                    jnp.asarray(fresh, jnp.int32),
-                    jnp.asarray(shared, jnp.int32), slot_arr)
+        self.metrics["primes"] += 1
+        self.metrics["queue_ms"] += (
+            self.clock.monotonic() - r.enqueued_s) * 1e3
+        with span("engine.prime", self.metrics, "prefill_ms") as prime:
+            if self._pool is not None:
+                shared: List[int] = []
+                if self._prefix is not None:
+                    _, shared = self._prefix.lookup(prompt, self.page_size)
+                prefix_tokens = len(shared) * self.page_size
+                fresh = self._alloc_pages(self._pages_needed(S) - len(shared))
+                slot.pages = list(shared) + fresh
+                self._tables[slot.index, :] = 0
+                self._tables[slot.index, :len(slot.pages)] = slot.pages
+                self._tables_dev.clear()
+                suffix = prompt[prefix_tokens:]
+                batch = {"tokens": jnp.asarray(suffix[None, :]),
+                         **self._batch_extras(1)}
+                if shared:
+                    shape = ("prime_past", len(suffix), prefix_tokens)
+                    self._cb_cache, tok = self._prime_past(
+                        self.params, batch, self._cb_cache,
+                        jnp.asarray(fresh, jnp.int32),
+                        jnp.asarray(shared, jnp.int32), slot_arr)
+                else:
+                    shape = ("prime", S)
+                    self._cb_cache, tok = self._prime(
+                        self.params, batch, self._cb_cache,
+                        jnp.asarray(fresh, jnp.int32), slot_arr)
+                if self._prefix is not None:
+                    # register this prompt's full blocks for future sharers
+                    self._prefix.insert(prompt, slot.pages, self.page_size)
+                pf_tokens = len(suffix)
             else:
+                shape = ("prime", S)
+                batch = {"tokens": jnp.asarray(prompt[None, :]),
+                         **self._batch_extras(1)}
                 self._cb_cache, tok = self._prime(
-                    self.params, batch, self._cb_cache,
-                    jnp.asarray(fresh, jnp.int32), slot_arr)
-            if self._prefix is not None:
-                # register this prompt's full blocks for future sharers
-                self._prefix.insert(prompt, slot.pages, self.page_size)
-            pf_tokens = len(suffix)
-        else:
-            batch = {"tokens": jnp.asarray(prompt[None, :]),
-                     **self._batch_extras(1)}
-            self._cb_cache, tok = self._prime(
-                self.params, batch, self._cb_cache, slot_arr)
-            pf_tokens = S
-        tok = int(np.asarray(jax.block_until_ready(tok))[0])
-        ms = (time.perf_counter() - t0) * 1e3
-        self.metrics["prefill_ms"] += ms
+                    self.params, batch, self._cb_cache, slot_arr)
+                pf_tokens = S
+            if self._new_shape(shape):
+                prime.tag(new_shape=1)
+            tok = int(np.asarray(jax.block_until_ready(tok))[0])
         if self.on_prefill_ms is not None:
-            self.on_prefill_ms(pf_tokens, ms)
-        slot.request, slot.pos, slot.token = r, S, tok
-        self._emit(r, tok)
-        self.metrics["tokens"] += 1
-        if r.done:                       # max_new_tokens == 1
-            self._finish(slot)
+            self.on_prefill_ms(pf_tokens, prime.ms)
+        with span("engine.emit"):
+            slot.request, slot.pos, slot.token = r, S, tok
+            self._emit(r, tok)
+            self.metrics["tokens"] += 1
+            if r.done:                       # max_new_tokens == 1
+                self._finish(slot)
 
     def _finish(self, slot: _Slot) -> None:
         r = slot.request
@@ -536,77 +592,95 @@ class ServingEngine:
             self.on_complete(r)
 
     def _admit_locked(self) -> None:
-        for slot in self._slots:
-            if slot.request is None and self._waiting:
-                self._prime_slot(slot, self._waiting.popleft())
+        with span("engine.admit"):
+            for slot in self._slots:
+                if slot.request is None and self._waiting:
+                    self._prime_slot(slot, self._waiting.popleft())
 
     def step(self) -> int:
         """Advance the shared decode batch one token.  Freed slots are
         re-primed from the waiting queue first, so sequences join and leave
         the batch every step.  Returns the number of live tokens emitted
         (0 = engine idle)."""
-        with self._lock:
+        # one frame from lock to release: moving the body into a helper
+        # called under the lock made waiting ``submit`` calls starve for
+        # many more steps (the driver re-takes the lock before they wake)
+        t0 = time.perf_counter()
+        with span("engine.step"), self._lock:
+            busy0 = self.metrics["prefill_ms"] + self.metrics["decode_ms"]
             self._admit_locked()
             live = [s for s in self._slots if s.request is not None]
             if not live:
+                self._count_host(t0, busy0)
                 return 0
-            tokens = np.zeros((self.batch_size, 1), np.int32)
-            posv = np.zeros((self.batch_size,), np.int32)
-            for s in self._slots:
-                tokens[s.index, 0] = s.token
-                posv[s.index] = s.pos
-            width = 0
-            if self._pool is not None:
-                for s in live:
-                    blk = s.pos // self.page_size
-                    if blk >= len(s.pages):
-                        # on-demand growth: this step's write position
-                        # crossed into a new block; the admission-time
-                        # reservation guarantees the allocation succeeds
-                        s.pages.extend(self._alloc_pages(1))
-                        self._tables[s.index, blk] = s.pages[-1]
-                        self._tables_dev.clear()
-                    width = max(width, len(s.pages))
-                # attend only over live pages: the table passed to the
-                # kernel is cropped to the widest live row, so short
-                # requests read 1-2 pages instead of a full max_seq-shaped
-                # row — the paged layout's bandwidth win.  Exact widths
-                # compile at most max_pages decode variants; wide tables
-                # bucket to powers of two to bound compile count.
-                if self.max_pages > 16:
-                    width = 1 << (width - 1).bit_length()
-                width = min(width, self.max_pages)
-            if self._pool is not None:
-                # tables change only on admission/growth/finish; steps in
-                # between reuse the uploaded device copy per width
-                tables = self._tables_dev.get(width)
-                if tables is None:
-                    tables = jnp.asarray(self._tables[:, :width])
-                    self._tables_dev[width] = tables
-            t0 = time.perf_counter()
-            if self._pool is not None:
-                self._cb_cache, logits = self._decode(
-                    self.params, self._cb_cache, jnp.asarray(tokens),
-                    jnp.asarray(posv), tables)
-            else:
-                self._cb_cache, logits = self._decode(
-                    self.params, self._cb_cache, jnp.asarray(tokens),
-                    jnp.asarray(posv))
-            logits = jax.block_until_ready(logits)
-            ms = (time.perf_counter() - t0) * 1e3
-            self.metrics["decode_ms"] += ms
+            with span("engine.prepare"):
+                tokens = np.zeros((self.batch_size, 1), np.int32)
+                posv = np.zeros((self.batch_size,), np.int32)
+                for s in self._slots:
+                    tokens[s.index, 0] = s.token
+                    posv[s.index] = s.pos
+                width = 0
+                if self._pool is not None:
+                    for s in live:
+                        blk = s.pos // self.page_size
+                        if blk >= len(s.pages):
+                            # on-demand growth: this step's write position
+                            # crossed into a new block; the admission-time
+                            # reservation guarantees the allocation succeeds
+                            s.pages.extend(self._alloc_pages(1))
+                            self._tables[s.index, blk] = s.pages[-1]
+                            self._tables_dev.clear()
+                        width = max(width, len(s.pages))
+                    # attend only over live pages: the table passed to the
+                    # kernel is cropped to the widest live row, so short
+                    # requests read 1-2 pages instead of a full max_seq-shaped
+                    # row — the paged layout's bandwidth win.  Exact widths
+                    # compile at most max_pages decode variants; wide tables
+                    # bucket to powers of two to bound compile count.
+                    if self.max_pages > 16:
+                        width = 1 << (width - 1).bit_length()
+                    width = min(width, self.max_pages)
+                    # tables change only on admission/growth/finish; steps in
+                    # between reuse the uploaded device copy per width
+                    tables = self._tables_dev.get(width)
+                    if tables is None:
+                        tables = jnp.asarray(self._tables[:, :width])
+                        self._tables_dev[width] = tables
+            new_shape = self._new_shape(("decode", width))
+            with span("engine.decode", self.metrics, "decode_ms") as decode:
+                if new_shape:
+                    decode.tag(new_shape=1)
+                if self._pool is not None:
+                    self._cb_cache, logits = self._decode(
+                        self.params, self._cb_cache, jnp.asarray(tokens),
+                        jnp.asarray(posv), tables)
+                else:
+                    self._cb_cache, logits = self._decode(
+                        self.params, self._cb_cache, jnp.asarray(tokens),
+                        jnp.asarray(posv))
+                logits = jax.block_until_ready(logits)
             self.metrics["decode_steps"] += 1
+            self.metrics["decode_rows"] += len(live)
             if self.on_step_ms is not None:
-                self.on_step_ms(ms)
-            tok = np.asarray(jnp.argmax(logits, axis=-1).astype(jnp.int32))
-            for s in live:
-                self._emit(s.request, int(tok[s.index]))
-                s.token = int(tok[s.index])
-                s.pos += 1
-                if s.request.done:
-                    self._finish(s)
-            self.metrics["tokens"] += len(live)
+                self.on_step_ms(decode.ms)
+            with span("engine.sample"):
+                tok = np.asarray(jnp.argmax(logits, axis=-1).astype(jnp.int32))
+            with span("engine.emit"):
+                for s in live:
+                    self._emit(s.request, int(tok[s.index]))
+                    s.token = int(tok[s.index])
+                    s.pos += 1
+                    if s.request.done:
+                        self._finish(s)
+                self.metrics["tokens"] += len(live)
+            self._count_host(t0, busy0)
             return len(live)
+
+    def _count_host(self, t0: float, busy0: float) -> None:
+        """Add this step's host time (all but its primes and decode) to
+        ``host_ms``."""
+        busy = self.metrics["prefill_ms"] + self.metrics["decode_ms"] - busy0
+        self.metrics["host_ms"] += (time.perf_counter() - t0) * 1e3 - busy
 
     def drain(self) -> None:
         """Run ``step`` until the queue and every slot are empty."""
@@ -663,6 +737,6 @@ class ServingEngine:
 
         while not stop.is_set():
             if self.step() == 0:
-                with self._work:
+                with span("engine.park"), self._work:
                     self.clock.wait_for(self._work, has_work,
                                         timeout=idle_wait_s)

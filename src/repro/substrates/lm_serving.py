@@ -17,6 +17,15 @@ lifecycle fault) instead of timing out mid-decode after burning batch
 slots.  Admitted requests should therefore never expire mid-decode; the
 engine counts any such miss in ``metrics["deadline_expired"]``.
 
+The engine's own telemetry stays with the engine: named host spans of each
+phase (``engine.step``, ``engine.prime``, ``engine.decode``, ``engine.park``,
+``engine.submit.lock`` ...; see ``repro.serving.engine``) land in a profile
+of this process beside the device's programs, and ``engine.metrics`` counts
+the lock wait of ``submit`` (``lock_wait_ms`` / ``submits``), the queue
+wait before a prime (``queue_ms`` / ``primes``), the host time of the
+decode loop (``host_ms``), live rows per step (``decode_rows``) and first
+dispatches of a program shape (``new_shapes``).
+
 One driver thread owns the decode loop (``ServingEngine.serve_forever``);
 ``invoke`` is called concurrently by many scheduler workers, each blocking
 on its request's completion event.  Prefill jit-compiles once per distinct
