@@ -278,11 +278,14 @@ def decode_attention(cfg, p: dict, x, cache: dict, pos, *,
 
 
 def paged_decode_attention(cfg, p: dict, x, cache: dict, pos, tables, *,
-                           page_size: int):
+                           page_size: int, layer=None):
     """One-token decode against a block-granular paged KV pool.
 
     cache k/v: (num_pages+1, page_size, K, hd) — row 0 is the null page
-    that dead batch rows write into and no one reads.
+    that dead batch rows write into and no one reads.  With ``layer``, k/v
+    are the whole layer-stacked pool (reps, num_pages+1, page_size, K, hd)
+    and only layer ``layer`` is written and read: one scatter and one
+    gather on the stack, so a scan carrying it updates it in place.
     tables: (B, max_pages) int32 page ids (0 where unallocated) — the
     per-row page-index vectors generalizing the per-row position vectors.
     pos: (B,) per-row absolute positions.  The engine guarantees every
@@ -301,12 +304,13 @@ def paged_decode_attention(cfg, p: dict, x, cache: dict, pos, tables, *,
     b = jnp.arange(B)
     pid = tables[b, pos // jnp.int32(page_size)]  # (B,) write page per row
     off = pos % jnp.int32(page_size)
-    k_pool = k_pool.at[pid, off].set(k_new[:, 0].astype(k_pool.dtype))
-    v_pool = v_pool.at[pid, off].set(v_new[:, 0].astype(v_pool.dtype))
+    at = () if layer is None else (layer,)
+    k_pool = k_pool.at[at + (pid, off)].set(k_new[:, 0].astype(k_pool.dtype))
+    v_pool = v_pool.at[at + (pid, off)].set(v_new[:, 0].astype(v_pool.dtype))
     K, hd = k_pool.shape[-2], k_pool.shape[-1]
     T = tables.shape[1] * page_size
-    k = k_pool[tables].reshape(B, T, K, hd)       # gather through the table
-    v = v_pool[tables].reshape(B, T, K, hd)
+    k = k_pool[at + (tables,)].reshape(B, T, K, hd)   # gather through the table
+    v = v_pool[at + (tables,)].reshape(B, T, K, hd)
     idx = jnp.arange(T, dtype=jnp.int32)
     kv_valid = idx[None, :] <= pos[:, None]
     H = q.shape[2]

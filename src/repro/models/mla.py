@@ -191,12 +191,14 @@ def mla_decode(cfg, p: dict, x, cache: dict, pos):
 
 
 def mla_paged_decode(cfg, p: dict, x, cache: dict, pos, tables, *,
-                     page_size: int):
+                     page_size: int, layer=None):
     """Absorbed decode against a block-granular paged latent pool.
 
     cache c_kv: (num_pages+1, page_size, kv_lora); k_rope likewise — row 0
-    is the null page.  tables: (B, max_pages) int32 page ids (0 where
-    unallocated); pos: (B,) per-row absolute positions.  Same engine
+    is the null page.  With ``layer``, both are the whole layer-stacked
+    pool and only layer ``layer`` is written and read, as in
+    ``paged_decode_attention``.  tables: (B, max_pages) int32 page ids (0
+    where unallocated); pos: (B,) per-row absolute positions.  Same engine
     guarantees as ``paged_decode_attention``: valid positions are backed
     by real pages and the write page is private to its row.
     """
@@ -209,13 +211,14 @@ def mla_paged_decode(cfg, p: dict, x, cache: dict, pos, tables, *,
     b = jnp.arange(B)
     pid = tables[b, pos // jnp.int32(page_size)]
     off = pos % jnp.int32(page_size)
-    c_pool = cache["c_kv"].at[pid, off].set(
+    at = () if layer is None else (layer,)
+    c_pool = cache["c_kv"].at[at + (pid, off)].set(
         c_new[:, 0].astype(cache["c_kv"].dtype))
-    kr_pool = cache["k_rope"].at[pid, off].set(
+    kr_pool = cache["k_rope"].at[at + (pid, off)].set(
         kr_new[:, 0].astype(cache["k_rope"].dtype))
     T = tables.shape[1] * page_size
-    c_kv = c_pool[tables].reshape(B, T, a.kv_lora_rank)
-    k_rope = kr_pool[tables].reshape(B, T, a.qk_rope_head_dim)
+    c_kv = c_pool[at + (tables,)].reshape(B, T, a.kv_lora_rank)
+    k_rope = kr_pool[at + (tables,)].reshape(B, T, a.qk_rope_head_dim)
     idx = jnp.arange(T, dtype=jnp.int32)
     valid = (idx[None, :] <= pos[:, None])[:, None, None, :]
     out = _absorbed_read(cfg, p, x.dtype, q_nope, q_rope, c_kv, k_rope, valid)

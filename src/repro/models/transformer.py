@@ -316,12 +316,14 @@ def apply_layer_prefill(cfg, ld, p, x, positions, ctx, aux,
 
 
 def apply_layer_decode(cfg, ld, p, x, cache, pos, aux,
-                       tables=None, page_size=None):
+                       tables=None, page_size=None, layer=None):
     """x: (B,1,d). Returns (x, new_cache).
 
     With ``tables`` (paged serving), attn/mla leaves live in a shared page
     pool gathered through per-row page tables; resident mixers are
-    untouched — they keep per-row state and the per-row ``pos`` vector."""
+    untouched — they keep per-row state and the per-row ``pos`` vector.
+    With ``layer``, the pool leaves are the whole layer-stacked pool and
+    this layer's rows of it are updated in place."""
     x = constrain(x, ("batch", "act_seq", None))
     h = cm.apply_norm(cfg, p["ln1"], x)
     new_cache = dict(cache)
@@ -329,7 +331,7 @@ def apply_layer_decode(cfg, ld, p, x, cache, pos, aux,
         if tables is not None:
             out, kv = attn.paged_decode_attention(
                 cfg, p["mixer"], h, {"k": cache["k"], "v": cache["v"]}, pos,
-                tables, page_size=page_size)
+                tables, page_size=page_size, layer=layer)
         else:
             out, kv = attn.decode_attention(
                 cfg, p["mixer"], h, {"k": cache["k"], "v": cache["v"]}, pos)
@@ -344,7 +346,7 @@ def apply_layer_decode(cfg, ld, p, x, cache, pos, aux,
             out, kv = mla_mod.mla_paged_decode(
                 cfg, p["mixer"], h,
                 {"c_kv": cache["c_kv"], "k_rope": cache["k_rope"]}, pos,
-                tables, page_size=page_size)
+                tables, page_size=page_size, layer=layer)
         else:
             out, kv = mla_mod.mla_decode(cfg, p["mixer"], h,
                                          {"c_kv": cache["c_kv"],
@@ -531,16 +533,17 @@ class Stack:
                                                caches["prefix"][str(i)], pos, aux,
                                                tables=tables, page_size=page_size)
                 new["prefix"][str(i)] = c
-        if self.reps:
+        if self.reps and tables is not None:
+            x, new["blocks"], aux = self._decode_blocks_paged(
+                p["blocks"], x, caches["blocks"], pos, aux, tables, page_size)
+        elif self.reps:
             def body(carry, scanned):
                 x, aux = carry
                 bp, bc = scanned
                 ncs = {}
                 for i, d in enumerate(self.cycle):
                     x, c, aux = apply_layer_decode(cfg, d, bp[str(i)], x,
-                                                   bc[str(i)], pos, aux,
-                                                   tables=tables,
-                                                   page_size=page_size)
+                                                   bc[str(i)], pos, aux)
                     ncs[str(i)] = c
                 return (x, aux), ncs
             (x, aux), new["blocks"] = jax.lax.scan(
@@ -553,3 +556,35 @@ class Stack:
                                                tables=tables, page_size=page_size)
                 new["suffix"][str(i)] = c
         return x, new, aux
+
+    def _decode_blocks_paged(self, bp, x, caches, pos, aux, tables,
+                             page_size):
+        """The cycle's paged decode.  The stacked page pools ride in the
+        scan's carry, whole, and each layer scatters its new row into them
+        and gathers through the tables in place; resident leaves (small
+        per-row state) are scanned as usual.  Slicing a layer's pool out of
+        the stack and stacking it back would move the whole pool each step."""
+        cfg = self.cfg
+        flags = self.paged_flags()["blocks"]
+
+        def part(c, k, paged):
+            return {n: a for n, a in c.items() if flags[k][n] == paged}
+
+        def body(carry, scanned):
+            x, aux, pools = carry
+            lp, layer, res = scanned
+            pools, res = dict(pools), dict(res)
+            for i, d in enumerate(self.cycle):
+                k = str(i)
+                x, c, aux = apply_layer_decode(
+                    cfg, d, lp[k], x, {**res[k], **pools[k]}, pos, aux,
+                    tables=tables, page_size=page_size, layer=layer)
+                pools[k], res[k] = part(c, k, True), part(c, k, False)
+            return (x, aux, pools), res
+
+        pools = {k: part(c, k, True) for k, c in caches.items()}
+        res = {k: part(c, k, False) for k, c in caches.items()}
+        (x, aux, pools), res = jax.lax.scan(
+            body, (x, aux, pools),
+            (bp, jnp.arange(self.reps, dtype=jnp.int32), res))
+        return x, {i: {**res[i], **pools[i]} for i in caches}, aux

@@ -11,14 +11,18 @@ leaked pages.
 """
 import threading
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.configs import get_config, reduced
 from repro.core.errors import AdmissionRefused, ErrorCode
 from repro.core.simclock import VirtualClock
-from repro.models import model_specs
+from repro.models import (build_decode_step_paged, decode_cache_paged,
+                          model_specs)
 from repro.models.common import init_params
+from repro.models.transformer import Stack, apply_layer_decode
 from repro.roofline.serving import ServingCostModel
 from repro.serving import Request, ServingEngine
 
@@ -70,6 +74,69 @@ def test_paged_parity_token_for_token(fam):
         assert paged.pool_stats() == {}
     else:
         assert paged.pool_stats()["pool_pages"] == 48
+
+
+def _slice_scatter_gather(self, bp, x, caches, pos, aux, tables, page_size):
+    """Reference for the cycle's paged decode: each layer's pool sliced out
+    of the stack, its new row scattered and the tables gathered there, and
+    the layers' pools stacked back."""
+    def body(carry, scanned):
+        x, aux = carry
+        lp, lc = scanned
+        out = {}
+        for i, d in enumerate(self.cycle):
+            x, out[str(i)], aux = apply_layer_decode(
+                self.cfg, d, lp[str(i)], x, lc[str(i)], pos, aux,
+                tables=tables, page_size=page_size)
+        return (x, aux), out
+
+    (x, aux), new = jax.lax.scan(body, (x, aux), (bp, caches))
+    return x, new, aux
+
+
+@pytest.mark.parametrize("arch", ["internlm2-20b", "deepseek-v2-236b"])
+def test_paged_decode_in_place_matches_per_layer_reference(arch, monkeypatch):
+    """The decode step that updates the stacked pool in place gives the
+    same logits and pool, bit for bit, as slicing each layer's pool out:
+    over steps that cross page boundaries, rows at different positions,
+    and a dead row that writes into null page 0."""
+    cfg = reduced(get_config(arch))
+    params = init_params(model_specs(cfg), seed=3)
+    page, pages = 4, 20
+    tables = jnp.asarray([[1, 2, 3, 0, 0, 0],       # 6..9: page 2 -> 3
+                          [4, 5, 6, 7, 10, 0],      # 13..16: page 7 -> 10
+                          [8, 9, 0, 0, 0, 0],       # 2..5: page 8 -> 9
+                          [0, 0, 0, 0, 0, 0]],      # dead: null page 0
+                         jnp.int32)
+    pos = np.array([6, 13, 2, 0], np.int32)
+    live = np.array([1, 1, 1, 0], np.int32)
+    B, max_seq = tables.shape[0], tables.shape[1] * page
+    keys = iter(jax.random.split(jax.random.PRNGKey(5), 64))
+    cache = jax.tree.map(
+        lambda a: jax.random.normal(next(keys), a.shape).astype(a.dtype),
+        decode_cache_paged(cfg, B, max_seq, pages, page, abstract=True))
+    tok = jnp.zeros((B, 1), jnp.int32)
+    args = (params, cache, tok, jnp.asarray(pos), tables)
+    step = jax.jit(build_decode_step_paged(cfg, page)).lower(*args).compile()
+    with monkeypatch.context() as m:
+        m.setattr(Stack, "_decode_blocks_paged", _slice_scatter_gather)
+        ref = jax.jit(build_decode_step_paged(cfg, page)).lower(*args).compile()
+    rng = np.random.default_rng(7)
+    got = want = cache
+    for _ in range(4):
+        tok = jnp.asarray(make_prompt(rng, cfg, B)[:, None])
+        got, logits = step(params, got, tok, jnp.asarray(pos), tables)
+        want, ref_logits = ref(params, want, tok, jnp.asarray(pos), tables)
+        np.testing.assert_array_equal(np.asarray(logits),
+                                      np.asarray(ref_logits))
+        jax.tree.map(lambda a, b: np.testing.assert_array_equal(
+            np.asarray(a), np.asarray(b)), got, want)
+        pos = pos + live
+    # the dead row wrote only null page 0; pages no table names are intact
+    untouched = np.arange(11, pages + 1)
+    jax.tree.map(lambda a, b: np.testing.assert_array_equal(
+        np.asarray(a)[:, untouched], np.asarray(b)[:, untouched]),
+        got["blocks"], cache["blocks"])
 
 
 def test_prefix_reuse_parity_and_suffix_only_prefill(attn):

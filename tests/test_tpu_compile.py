@@ -8,6 +8,8 @@ inside a fixture, never at import, so every test worker collects the same
 tests and only the worker given this file loads the TPU library.
 """
 import dataclasses
+import math
+import re
 
 import jax
 import jax.numpy as jnp
@@ -117,8 +119,7 @@ def _assert_fits(compiled):
                                    ma.temp_size_in_bytes)
 
 
-@pytest.mark.parametrize("paged", [False, True], ids=["slot", "paged"])
-def test_one_chip_decode_step_compiles_and_fits(one_chip, paged):
+def _compile_decode_step(one_chip, paged):
     from repro.models import (build_decode_step, build_decode_step_paged,
                               decode_cache, decode_cache_paged)
 
@@ -132,13 +133,34 @@ def test_one_chip_decode_step_compiles_and_fits(one_chip, paged):
                                              PAGE, abstract=True), one_chip)
         tables = _sd(one_chip, (BATCH, MAX_SEQ // PAGE), jnp.int32)
         step = jax.jit(build_decode_step_paged(cfg, PAGE), donate_argnums=1)
-        compiled = step.lower(params, cache, tok, pos, tables).compile()
-    else:
-        cache = _abstract(decode_cache(cfg, BATCH, MAX_SEQ, abstract=True),
-                          one_chip)
-        step = jax.jit(build_decode_step(cfg), donate_argnums=1)
-        compiled = step.lower(params, cache, tok, pos).compile()
+        return step.lower(params, cache, tok, pos, tables).compile(), cache
+    cache = _abstract(decode_cache(cfg, BATCH, MAX_SEQ, abstract=True),
+                      one_chip)
+    step = jax.jit(build_decode_step(cfg), donate_argnums=1)
+    return step.lower(params, cache, tok, pos).compile(), cache
+
+
+@pytest.mark.parametrize("paged", [False, True], ids=["slot", "paged"])
+def test_one_chip_decode_step_compiles_and_fits(one_chip, paged):
+    compiled, _ = _compile_decode_step(one_chip, paged)
     _assert_fits(compiled)
+
+
+def test_one_chip_paged_decode_updates_the_pool_in_place(one_chip):
+    """The stacked KV pool rides in the layer scan's carry: each step
+    scatters its new rows into it and gathers through the tables, and never
+    copies, slices out or writes back a layer's pool or the whole stack."""
+    compiled, cache = _compile_decode_step(one_chip, paged=True)
+    stack = cache["blocks"]["0"]["k"].shape       # (layers, pages+1, ...)
+    pool_elems = {math.prod(stack), math.prod(stack[1:])}
+    moves = [m.group(0) for m in re.finditer(
+        r"= \w+\[([\d,]*)\]\S* (copy|dynamic-slice|dynamic-update-slice)\(",
+        compiled.as_text())
+        if math.prod(int(d) for d in m.group(1).split(",") if d) in pool_elems]
+    assert not moves, moves
+    leaf_bytes = math.prod(stack) * cache["blocks"]["0"]["k"].dtype.itemsize
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < leaf_bytes, (temp, leaf_bytes)
 
 
 def test_one_chip_prefill_runs_the_flash_kernel(one_chip, compiled_kernels):
