@@ -76,7 +76,6 @@ class RWKVConfig:
     head_dim: int = 64
     decay_lora: int = 64
     mix_lora: int = 32
-    gate_lora: int = 64
 
 
 @dataclasses.dataclass(frozen=True)
@@ -253,7 +252,7 @@ def reduced(cfg: ArchConfig, **overrides) -> ArchConfig:
     if cfg.recurrent is not None:
         small["recurrent"] = RecurrentConfig(lru_width=64, conv_width=4, c=8.0)
     if cfg.rwkv is not None:
-        small["rwkv"] = RWKVConfig(head_dim=16, decay_lora=8, mix_lora=8, gate_lora=8)
+        small["rwkv"] = RWKVConfig(head_dim=16, decay_lora=8, mix_lora=8)
         small["num_heads"] = 4
         small["head_dim"] = 16
     small.update(overrides)

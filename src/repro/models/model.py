@@ -38,6 +38,9 @@ def model_specs(cfg) -> dict:
         "decoder": _decoder(cfg).specs(),
         "final_norm": cm.norm_spec(cfg, cfg.d_model),
     }
+    if cfg.family == "rwkv":
+        # RWKV normalises the embedding before the first block
+        s["ln0"] = cm.norm_spec(cfg, cfg.d_model)
     if not cfg.tie_embeddings:
         s["unembed"] = cm.ParamSpec((cfg.d_model, cfg.vocab_size),
                                     ("embed", "vocab"), dt)
@@ -72,6 +75,8 @@ def _sinusoid(positions, d_model: int):
 
 def _embed_tokens(cfg, params, tokens):
     x = params["embed"][tokens]
+    if cfg.family == "rwkv":
+        x = cm.apply_norm(cfg, params["ln0"], x)
     return constrain(x.astype(jnp.dtype(cfg.compute_dtype)),
                      ("batch", "act_seq", None))
 

@@ -5,7 +5,8 @@ Recurrence per head (state S ∈ R^{hd×hd}, fp32):
     S_t = diag(w_t) · S_{t-1} + k_tᵀ v_t
     y_t = r_t · (S_{t-1} + diag(u) · k_tᵀ v_t)
 
-with per-channel, per-token decay  w_t = exp(-exp(w0 + lora_w(x̃_t))) ∈ (0,1).
+with per-channel, per-token decay  w_t = exp(-exp(w0 + lora_w(x̃_t))) ∈ (0,1),
+and the output gated by a full-rank projection, g_t = silu(x̃_g · W_g).
 
 Training uses the *chunked* parallel form (chunk length ``CHUNK``): within a
 chunk the pairwise decay exponent  cum_{t-1} − cum_j  (j < t) is materialized
@@ -28,7 +29,7 @@ _MIX = 5  # w, k, v, r, g
 def rwkv_specs(cfg) -> dict:
     d, h = cfg.d_model, cfg.num_heads
     hd = cfg.rwkv.head_dim
-    dl, ml, gl = cfg.rwkv.decay_lora, cfg.rwkv.mix_lora, cfg.rwkv.gate_lora
+    dl, ml = cfg.rwkv.decay_lora, cfg.rwkv.mix_lora
     dt = jnp.dtype(cfg.param_dtype)
     return {
         "mu_x": cm.ParamSpec((d,), ("embed",), jnp.float32, "small"),
@@ -42,8 +43,7 @@ def rwkv_specs(cfg) -> dict:
         "w_r": cm.ParamSpec((d, h, hd), ("embed", "heads", None), dt),
         "w_k": cm.ParamSpec((d, h, hd), ("embed", "heads", None), dt),
         "w_v": cm.ParamSpec((d, h, hd), ("embed", "heads", None), dt),
-        "w_g": cm.ParamSpec((d, gl), ("embed", "lora"), dt),
-        "w_g2": cm.ParamSpec((gl, h, hd), ("lora", "heads", None), dt),
+        "w_g": cm.ParamSpec((d, h, hd), ("embed", "heads", None), dt),
         "ln_x": cm.ParamSpec((h, hd), ("heads", None), jnp.float32, "zeros"),
         "ln_x_b": cm.ParamSpec((h, hd), ("heads", None), jnp.float32, "zeros"),
         "w_o": cm.ParamSpec((h, hd, d), ("heads", None, "embed"), dt),
@@ -70,8 +70,7 @@ def _projections(cfg, p, x, x_prev):
     r = constrain_qkv(jnp.einsum("bsd,dhk->bshk", xr, p["w_r"]))
     k = constrain_qkv(jnp.einsum("bsd,dhk->bshk", xk, p["w_k"]))
     v = constrain_qkv(jnp.einsum("bsd,dhk->bshk", xv, p["w_v"]))
-    g = jax.nn.silu(jnp.einsum("bsl,lhk->bshk", jnp.tanh(
-        jnp.einsum("bsd,dl->bsl", xg, p["w_g"])), p["w_g2"]))
+    g = jax.nn.silu(jnp.einsum("bsd,dhk->bshk", xg, p["w_g"]))
     w_raw = p["w0"].astype(jnp.float32) + jnp.einsum(
         "bsl,ld->bsd", jnp.tanh(jnp.einsum("bsd,dl->bsl", xw, p["td_w1"])),
         p["td_w2"]).astype(jnp.float32)

@@ -153,7 +153,9 @@ class ServingEngine:
     ``queue_ms`` (each primed request's wait from ``enqueued_s`` to its
     prime, on the engine clock); ``host_ms`` (each ``engine.step`` less its
     primes and decode); ``decode_rows`` (live rows summed over decode
-    steps); ``new_shapes`` (dispatches of a prime length, a (suffix,
+    steps); ``state_rows`` (rows of resident state the decode steps carried,
+    live or not: ``batch_size`` a step on the slot-granular path, 0 where
+    every leaf pages); ``new_shapes`` (dispatches of a prime length, a (suffix,
     prefix) pair or a decode width not run before: where a trace or a
     compile lands).
     """
@@ -204,11 +206,17 @@ class ServingEngine:
         # private cache and is the baseline the paged path is judged against)
         self._decode_dense = (self._decode if self._pool is None else
                               jax.jit(build_decode_step(cfg), donate_argnums=1))
+        #: rows of resident state each decode step carries: every row on the
+        #: slot-granular path, live or not; on the paged path every row if
+        #: some leaf does not page (recurrent state, ring buffers), else none
+        self._state_rows = (batch_size if self._pool is None
+                            or not all(jax.tree.leaves(self._flags)) else 0)
         self.metrics: Dict[str, float] = {
             "prefill_ms": 0.0, "decode_ms": 0.0, "decode_steps": 0,
             "tokens": 0, "requests": 0, "deadline_expired": 0,
             "submits": 0, "lock_wait_ms": 0.0, "primes": 0, "queue_ms": 0.0,
-            "host_ms": 0.0, "decode_rows": 0, "new_shapes": 0}
+            "host_ms": 0.0, "decode_rows": 0, "state_rows": 0,
+            "new_shapes": 0}
         #: program shapes the continuous path has dispatched
         self._shapes: set = set()
         # continuous-batching state
@@ -661,6 +669,7 @@ class ServingEngine:
                 logits = jax.block_until_ready(logits)
             self.metrics["decode_steps"] += 1
             self.metrics["decode_rows"] += len(live)
+            self.metrics["state_rows"] += self._state_rows
             if self.on_step_ms is not None:
                 self.on_step_ms(decode.ms)
             with span("engine.sample"):

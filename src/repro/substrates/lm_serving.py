@@ -23,7 +23,8 @@ phase (``engine.step``, ``engine.prime``, ``engine.decode``, ``engine.park``,
 of this process beside the device's programs, and ``engine.metrics`` counts
 the lock wait of ``submit`` (``lock_wait_ms`` / ``submits``), the queue
 wait before a prime (``queue_ms`` / ``primes``), the host time of the
-decode loop (``host_ms``), live rows per step (``decode_rows``) and first
+decode loop (``host_ms``), live rows per step (``decode_rows``), rows of
+resident state carried per step (``state_rows``) and first
 dispatches of a program shape (``new_shapes``).
 
 One driver thread owns the decode loop (``ServingEngine.serve_forever``);

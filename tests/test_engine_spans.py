@@ -2,8 +2,9 @@
 
 Each phase of the engine is a named ``TraceAnnotation`` (``serving/spans.py``)
 so a profile puts device-idle time down to a host phase; ``engine.metrics``
-counts the lock wait, the queue wait, the host time per step, the live rows
-and the first dispatch of each program shape.  These tests read a profile
+counts the lock wait, the queue wait, the host time per step, the live rows,
+the rows of resident state carried and the first dispatch of each program
+shape.  These tests read a profile
 of a tiny paged engine and check the counters against what the engine was
 given and what it dispatched.
 """
@@ -101,6 +102,21 @@ def test_counters_hold_their_identities(attn):
     for key in ("lock_wait_ms", "queue_ms", "host_ms"):
         assert m[key] >= 0.0, key
     assert m["host_ms"] > 0.0
+
+
+@pytest.mark.parametrize("arch, paged, rows", [
+    ("rwkv6-7b", False, 3),        # slot-granular: every row, live or not
+    ("internlm2-20b", True, 0),    # every leaf pages: no resident rows
+])
+def test_state_rows_counts_resident_rows(arch, paged, rows):
+    cfg = reduced(get_config(arch))
+    eng = ServingEngine(cfg, params=init_params(model_specs(cfg), seed=1),
+                        batch_size=3, max_seq=64, paged=paged, page_size=8)
+    serve(eng, prompts(cfg, 5, (5, 12)), max_new=4)
+    m = eng.metrics
+    assert m["decode_steps"] > 0
+    assert m["decode_rows"] < 3 * m["decode_steps"]    # a row stayed dead
+    assert m["state_rows"] == rows * m["decode_steps"]
 
 
 def test_virtual_clock_stamps_and_queue_wait(attn):

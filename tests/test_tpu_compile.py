@@ -146,6 +146,32 @@ def test_one_chip_decode_step_compiles_and_fits(one_chip, paged):
     _assert_fits(compiled)
 
 
+def test_rwkv6_one_chip_decode_step_compiles_and_fits(one_chip):
+    """The benchmark's RWKV-6 configuration (published widths, 16 of 32
+    layers) at its 64 rows of resident state, slot-granular."""
+    import json
+    import sys
+    from pathlib import Path
+
+    from repro.models import build_decode_step, decode_cache
+
+    root = Path(__file__).resolve().parents[1]
+    if str(root) not in sys.path:
+        sys.path.insert(0, str(root))
+    from chipbench.harness import program
+
+    cfgj = json.loads(
+        (root / "chipbench" / "configs" / "rwkv6-7b-1chip.json").read_text())
+    cfg = program.arch_config(cfgj)
+    rows, max_seq = cfgj["engine"]["batch_size"], cfgj["engine"]["max_seq"]
+    cache = _abstract(decode_cache(cfg, rows, max_seq, abstract=True),
+                      one_chip)
+    step = jax.jit(build_decode_step(cfg), donate_argnums=1)
+    _assert_fits(step.lower(_params(cfg, one_chip), cache,
+                            _sd(one_chip, (rows, 1), jnp.int32),
+                            _sd(one_chip, (rows,), jnp.int32)).compile())
+
+
 def test_one_chip_paged_decode_updates_the_pool_in_place(one_chip):
     """The stacked KV pool rides in the layer scan's carry: each step
     scatters its new rows into it and gathers through the tables, and never
