@@ -166,7 +166,9 @@ def rwkv_decode(cfg, p: dict, x1, state, x_prev):
     rf, kf, vf = (t.astype(jnp.float32)[:, 0] for t in (r, k, v))  # (B,H,hd)
     w = jnp.exp(lw[:, 0])                                          # (B,H,hd)
     u = p["u"].astype(jnp.float32)
-    kv = jnp.einsum("bhd,bhe->bhde", kf, vf)
+    # the outer product as a broadcast multiply, not a batched dot: XLA then
+    # fuses the state's read into its in-place write in the layer scan
+    kv = kf[..., :, None] * vf[..., None, :]
     y = jnp.einsum("bhd,bhde->bhe", rf, state + u[None, :, :, None] * kv)
     state = state * w[..., None] + kv
     out = _readout(cfg, p, y[:, None].astype(x1.dtype), g, x1.dtype)

@@ -533,21 +533,9 @@ class Stack:
                                                caches["prefix"][str(i)], pos, aux,
                                                tables=tables, page_size=page_size)
                 new["prefix"][str(i)] = c
-        if self.reps and tables is not None:
-            x, new["blocks"], aux = self._decode_blocks_paged(
+        if self.reps:
+            x, new["blocks"], aux = self._decode_blocks(
                 p["blocks"], x, caches["blocks"], pos, aux, tables, page_size)
-        elif self.reps:
-            def body(carry, scanned):
-                x, aux = carry
-                bp, bc = scanned
-                ncs = {}
-                for i, d in enumerate(self.cycle):
-                    x, c, aux = apply_layer_decode(cfg, d, bp[str(i)], x,
-                                                   bc[str(i)], pos, aux)
-                    ncs[str(i)] = c
-                return (x, aux), ncs
-            (x, aux), new["blocks"] = jax.lax.scan(
-                body, (x, aux), (p["blocks"], caches["blocks"]))
         if self.suffix:
             new["suffix"] = {}
             for i, d in enumerate(self.suffix):
@@ -557,34 +545,38 @@ class Stack:
                 new["suffix"][str(i)] = c
         return x, new, aux
 
-    def _decode_blocks_paged(self, bp, x, caches, pos, aux, tables,
-                             page_size):
-        """The cycle's paged decode.  The stacked page pools ride in the
-        scan's carry, whole, and each layer scatters its new row into them
-        and gathers through the tables in place; resident leaves (small
-        per-row state) are scanned as usual.  Slicing a layer's pool out of
-        the stack and stacking it back would move the whole pool each step."""
+    def _decode_blocks(self, bp, x, caches, pos, aux, tables, page_size):
+        """The cycle's decode.  The stacked cache rides in the layer scan's
+        carry, whole, and each layer updates its own index of it in place:
+        pageable leaves (with ``tables``) go to the layer as the whole pool,
+        which it scatters into and gathers from; every other leaf is sliced
+        at the layer and written back there.  Scanning the stack as xs and
+        re-stacking it as ys would copy all of it each step."""
         cfg = self.cfg
         flags = self.paged_flags()["blocks"]
 
-        def part(c, k, paged):
-            return {n: a for n, a in c.items() if flags[k][n] == paged}
+        def whole(k, n):
+            return tables is not None and flags[k][n]
 
         def body(carry, scanned):
-            x, aux, pools = carry
-            lp, layer, res = scanned
-            pools, res = dict(pools), dict(res)
+            x, aux, cs = carry
+            lp, layer = scanned
+            cs = dict(cs)
             for i, d in enumerate(self.cycle):
                 k = str(i)
+                c = {n: a if whole(k, n)
+                     else jax.lax.dynamic_index_in_dim(a, layer, 0, False)
+                     for n, a in cs[k].items()}
                 x, c, aux = apply_layer_decode(
-                    cfg, d, lp[k], x, {**res[k], **pools[k]}, pos, aux,
+                    cfg, d, lp[k], x, c, pos, aux,
                     tables=tables, page_size=page_size, layer=layer)
-                pools[k], res[k] = part(c, k, True), part(c, k, False)
-            return (x, aux, pools), res
+                cs[k] = {n: c[n] if whole(k, n)
+                         else jax.lax.dynamic_update_index_in_dim(
+                             a, c[n].astype(a.dtype), layer, 0)
+                         for n, a in cs[k].items()}
+            return (x, aux, cs), None
 
-        pools = {k: part(c, k, True) for k, c in caches.items()}
-        res = {k: part(c, k, False) for k, c in caches.items()}
-        (x, aux, pools), res = jax.lax.scan(
-            body, (x, aux, pools),
-            (bp, jnp.arange(self.reps, dtype=jnp.int32), res))
-        return x, {i: {**res[i], **pools[i]} for i in caches}, aux
+        (x, aux, caches), _ = jax.lax.scan(
+            body, (x, aux, caches),
+            (bp, jnp.arange(self.reps, dtype=jnp.int32)))
+        return x, caches, aux

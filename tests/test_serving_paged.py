@@ -119,7 +119,7 @@ def test_paged_decode_in_place_matches_per_layer_reference(arch, monkeypatch):
     args = (params, cache, tok, jnp.asarray(pos), tables)
     step = jax.jit(build_decode_step_paged(cfg, page)).lower(*args).compile()
     with monkeypatch.context() as m:
-        m.setattr(Stack, "_decode_blocks_paged", _slice_scatter_gather)
+        m.setattr(Stack, "_decode_blocks", _slice_scatter_gather)
         ref = jax.jit(build_decode_step_paged(cfg, page)).lower(*args).compile()
     rng = np.random.default_rng(7)
     got = want = cache

@@ -146,9 +146,11 @@ def test_one_chip_decode_step_compiles_and_fits(one_chip, paged):
     _assert_fits(compiled)
 
 
-def test_rwkv6_one_chip_decode_step_compiles_and_fits(one_chip):
+@pytest.fixture(scope="module")
+def rwkv6_decode(one_chip):
     """The benchmark's RWKV-6 configuration (published widths, 16 of 32
-    layers) at its 64 rows of resident state, slot-granular."""
+    layers) at its 64 rows of resident state, slot-granular: the compiled
+    decode step and its abstract cache."""
     import json
     import sys
     from pathlib import Path
@@ -167,9 +169,34 @@ def test_rwkv6_one_chip_decode_step_compiles_and_fits(one_chip):
     cache = _abstract(decode_cache(cfg, rows, max_seq, abstract=True),
                       one_chip)
     step = jax.jit(build_decode_step(cfg), donate_argnums=1)
-    _assert_fits(step.lower(_params(cfg, one_chip), cache,
-                            _sd(one_chip, (rows, 1), jnp.int32),
-                            _sd(one_chip, (rows,), jnp.int32)).compile())
+    compiled = step.lower(_params(cfg, one_chip), cache,
+                          _sd(one_chip, (rows, 1), jnp.int32),
+                          _sd(one_chip, (rows,), jnp.int32)).compile()
+    return compiled, cache
+
+
+def test_rwkv6_one_chip_decode_step_compiles_and_fits(rwkv6_decode):
+    _assert_fits(rwkv6_decode[0])
+
+
+def test_rwkv6_one_chip_decode_step_updates_the_state_in_place(rwkv6_decode):
+    """The stacked WKV state rides in the layer scan's carry, and each layer
+    reads and writes its own index of it in place: no buffer of the stack's
+    shape is allocated or copied into the output, and no layer's state is
+    copied out of it either."""
+    compiled, cache = rwkv6_decode
+    s = cache["blocks"]["0"]["s"]                 # (layers, rows, H, hd, hd)
+    assert s.dtype == jnp.float32
+    shape = f"f32[{','.join(map(str, s.shape))}]"
+    moves = [line.strip()[:160] for line in compiled.as_text().splitlines()
+             if (m := re.search(r"= (\w+\[[\d,]*\])\S* (copy|custom-call)\(",
+                                line))
+             and m.group(1) == shape
+             and (m.group(2) == "copy" or "AllocateBuffer" in line)]
+    assert not moves, moves
+    layer_bytes = math.prod(s.shape[1:]) * s.dtype.itemsize
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < layer_bytes, (temp, layer_bytes)
 
 
 def test_one_chip_paged_decode_updates_the_pool_in_place(one_chip):
