@@ -29,15 +29,14 @@
 #                          >= 2x fewer requests than cursor polling)
 #   make serving-smoke     LM serving drill: engine/adapter + paged-KV
 #                          suites (allocator properties, paged/contiguous
-#                          parity), quick continuous-batching + paged
-#                          trials and a 16-session gateway flood
+#                          parity), quick paged trials and a 16-session
+#                          gateway flood
 #                          (structured DEADLINE/QUEUE_SATURATED refusals,
 #                          zero mid-decode expiries, zero page leaks)
-#   make bench-serving     full LM serving benchmark: continuous vs fixed
-#                          batch goodput on a mixed-length trace (asserts
-#                          >= 2x), paged-KV parity/capacity/prefix gates
-#                          (>= 1x goodput, 2x capacity, >= 30% TTFT cut)
-#                          + 128 concurrent gateway sessions (bounded
+#   make bench-serving     full LM serving benchmark: paged-KV
+#                          parity/capacity/prefix gates on a mixed-length
+#                          trace (>= 1x goodput, 2x capacity, >= 30% TTFT
+#                          cut) + 128 concurrent gateway sessions (bounded
 #                          p99 TTFT, admission refusals)
 #   make test-sim          virtual-time suites: clock semantics, scheduler
 #                          timebase regressions, simulator invariants

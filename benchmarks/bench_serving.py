@@ -1,23 +1,8 @@
-"""LM serving bench: continuous-batching goodput + admission under load.
+"""LM serving bench: the paged KV layout and admission under load.
 
-The serving tentpole makes two measurable claims; each gets a section and
-an assert, 3 committed trials in ``results/bench_serving.json``.
-
-- **goodput** — one mixed-length arrival trace (small prompt-length set so
-  prefill compiles stay bounded; heavy-tailed ``max_new_tokens`` so a few
-  long decodes pin any fixed group) served two ways on the same params:
-
-  * *fixed* — the run-to-completion baseline: requests grouped in arrival
-    order into batches of ``BATCH``, each group holding its slots until
-    the group's longest request finishes (head-of-line blocking + idle
-    slots after short rows retire);
-  * *continuous* — the same requests through ``submit()`` + the decode
-    loop: finished rows leave the batch each step, freed slots re-primed
-    from fresh prefills.
-
-  Goodput = generated tokens / wall second.  Acceptance: continuous >=
-  2x fixed on the full run (the ratio is exactly the fixed path's slot
-  idleness, paid back).
+Two sections, each with its asserts, 3 committed trials in
+``results/bench_serving.json``.  Every figure is a CPU figure at the toy
+``reduced()`` widths, not a device metric.
 
 - **concurrency** — one ``LmServingAdapter`` behind a real
   ``ControlPlaneGateway``; ``SESSIONS`` (>= 128 full-run) client threads
@@ -34,9 +19,11 @@ an assert, 3 committed trials in ``results/bench_serving.json``.
 - **paged** — the paged-KV cache (PR 10) against the slot-granular layout
   it replaces, same params, four sub-claims:
 
-  * *parity/goodput* — the mixed-length trace served continuously on both
-    layouts must be token-for-token identical, at >= 1.0x goodput with the
-    paged pool holding a fraction of the slot-granular KV HBM.  The paged
+  * *parity/goodput* — a mixed-length arrival trace (small prompt-length
+    set so prefill compiles stay bounded; heavy-tailed ``max_new_tokens``)
+    served on both layouts must be token-for-token identical, at >= 1.0x
+    goodput (generated tokens / wall second) with the paged pool holding a
+    fraction of the slot-granular KV HBM.  The paged
     producer uses admission backpressure: on ``QUEUE_SATURATED`` it steps
     the engine and retries, so the pool only covers live + queued
     reservations (batch x worst-case pages per request), not the whole
@@ -61,7 +48,6 @@ should not fail on throughput weather.
 """
 from __future__ import annotations
 
-import statistics
 import threading
 import time
 from collections import deque
@@ -71,15 +57,14 @@ from benchmarks.common import csv_row, save
 
 N_TRIALS = 3
 
-# -- goodput trace (full run) -------------------------------------------------
+# -- mixed-length trace (full run) --------------------------------------------
 BATCH = 8
 MAX_SEQ = 128
 N_REQS = 64
 PROMPT_LENS = (6, 7, 8, 9)        # small set: prefill compiles stay bounded
 LIGHT_MAX_NEW = (2, 3)
-HEAVY_MAX_NEW = 64                # the tail that pins a fixed batch
+HEAVY_MAX_NEW = 64                # the heavy tail of decode budgets
 HEAVY_EVERY = 8                   # 1 in 8 requests is heavy
-GOODPUT_RATIO_MIN = 2.0
 
 # -- paged kv -----------------------------------------------------------------
 PAGE_SIZE = 16
@@ -117,87 +102,6 @@ def _trace(rng, cfg, n_reqs: int, heavy_max_new: int):
             else int(rng.choice(LIGHT_MAX_NEW))
         out.append((prompt, max_new))
     return out
-
-
-def _fixed_run(eng, trace) -> Dict:
-    """Run-to-completion baseline: arrival-order groups of ``batch_size``."""
-    from repro.serving import Request
-
-    reqs = [Request(f"f{i}", p, max_new_tokens=mn)
-            for i, (p, mn) in enumerate(trace)]
-    b = eng.batch_size
-    t0 = time.perf_counter()
-    for i in range(0, len(reqs), b):
-        eng.generate(reqs[i:i + b])
-    wall_s = time.perf_counter() - t0
-    tokens = sum(len(r.generated) for r in reqs)
-    assert all(r.done and len(r.generated) == r.max_new_tokens for r in reqs)
-    return {"tokens": tokens, "wall_s": wall_s,
-            "tokens_per_s": tokens / wall_s}
-
-
-def _continuous_run(eng, trace) -> Dict:
-    """Same trace through the continuous path: submit all, drain."""
-    from repro.serving import Request
-
-    reqs = [Request(f"c{i}", p, max_new_tokens=mn)
-            for i, (p, mn) in enumerate(trace)]
-    t0 = time.perf_counter()
-    for r in reqs:
-        eng.submit(r)
-    eng.drain()
-    wall_s = time.perf_counter() - t0
-    tokens = sum(len(r.generated) for r in reqs)
-    assert all(r.done and len(r.generated) == r.max_new_tokens for r in reqs)
-    ttfts = [r.ttft_ms for r in reqs if r.ttft_ms is not None]
-    return {"tokens": tokens, "wall_s": wall_s,
-            "tokens_per_s": tokens / wall_s,
-            "ttft_p50_ms": _pct(ttfts, 0.50), "ttft_p99_ms": _pct(ttfts, 0.99)}
-
-
-def _goodput_section(smoke: bool) -> Dict:
-    import numpy as np
-
-    from repro.configs import get_config, reduced
-    from repro.models import model_specs
-    from repro.models.common import init_params
-    from repro.serving import ServingEngine
-
-    cfg = reduced(get_config(ARCH))
-    params = init_params(model_specs(cfg), seed=1)
-    batch = 4 if smoke else BATCH
-    n_reqs = 12 if smoke else N_REQS
-    heavy = 24 if smoke else HEAVY_MAX_NEW
-    fixed_eng = ServingEngine(cfg, params=params, batch_size=batch,
-                              max_seq=MAX_SEQ)
-    cont_eng = ServingEngine(cfg, params=params, batch_size=batch,
-                             max_seq=MAX_SEQ)
-    # identical trace every trial (shapes compile once in the warmup;
-    # trials then measure steady-state serving, not XLA compile weather)
-    trace = _trace(np.random.default_rng(7), cfg, n_reqs, heavy)
-    _fixed_run(fixed_eng, trace)
-    _continuous_run(cont_eng, trace)
-    trials = []
-    for _ in range(1 if smoke else N_TRIALS):
-        fixed = _fixed_run(fixed_eng, trace)
-        cont = _continuous_run(cont_eng, trace)
-        trials.append({"fixed": fixed, "continuous": cont,
-                       "goodput_ratio": cont["tokens_per_s"]
-                       / fixed["tokens_per_s"]})
-    ratios = [t["goodput_ratio"] for t in trials]
-    section = {
-        "batch_size": batch, "n_requests": n_reqs,
-        "prompt_lens": list(PROMPT_LENS), "heavy_max_new": heavy,
-        "heavy_every": HEAVY_EVERY, "light_max_new": list(LIGHT_MAX_NEW),
-        "trials": trials,
-        "goodput_ratio_median": statistics.median(ratios),
-        "goodput_ratio_min": min(ratios),
-    }
-    if not smoke:
-        assert min(ratios) >= GOODPUT_RATIO_MIN, \
-            f"continuous batching goodput ratio {min(ratios):.2f} " \
-            f"< {GOODPUT_RATIO_MIN}x over fixed-batch baseline"
-    return section
 
 
 def _paged_section(smoke: bool) -> Dict:
@@ -492,21 +396,13 @@ def _concurrency_section(smoke: bool) -> Dict:
 
 def run(fast_service, smoke: bool = False) -> List[str]:
     del fast_service                    # serving brings its own substrate
-    goodput = _goodput_section(smoke)
     paged = _paged_section(smoke)
     conc = _concurrency_section(smoke)
     payload = {"arch": ARCH, "max_seq": MAX_SEQ, "smoke": smoke,
-               "goodput": goodput, "paged": paged, "concurrency": conc}
+               "paged": paged, "concurrency": conc}
     save("bench_serving_smoke" if smoke else "bench_serving", payload)
-    best = max(t["continuous"]["tokens_per_s"] for t in goodput["trials"])
-    fixed = max(t["fixed"]["tokens_per_s"] for t in goodput["trials"])
     t0 = conc["trials"][0]
     return [
-        csv_row("serving_fixed_tokens_per_s", fixed,
-                f"batch={goodput['batch_size']} run-to-completion"),
-        csv_row("serving_continuous_tokens_per_s", best,
-                f"goodput_ratio_median="
-                f"{goodput['goodput_ratio_median']:.2f}x"),
         csv_row("serving_paged_tokens_per_s",
                 max(t["paged"]["tokens_per_s"] for t in paged["trials"]),
                 f"vs_dense={paged['goodput_ratio_best']:.2f}x "

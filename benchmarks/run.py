@@ -31,12 +31,12 @@ Paper artifact map:
                         (vs the single-hop wire margin) and streaming
                         telemetry fan-in vs the N-cursor polling baseline
                         (request count + zero-loss by sequence numbers)
-    bench_serving     — beyond-paper LM serving substrate: continuous
-                        batching vs fixed-batch goodput on a mixed-length
-                        arrival trace (>= 2x), p99 TTFT + structured
-                        DEADLINE admission refusals under >= 128
-                        concurrent gateway sessions (zero mid-decode
-                        expiries for admitted requests)
+    bench_serving     — beyond-paper LM serving substrate: paged-KV
+                        parity, capacity and prefix TTFT against the
+                        slot-granular layout on a mixed-length arrival
+                        trace, p99 TTFT + structured DEADLINE admission
+                        refusals under >= 128 concurrent gateway sessions
+                        (zero mid-decode expiries for admitted requests)
 """
 import argparse
 import sys

@@ -1,10 +1,9 @@
-"""LM serving example: fixed-batch vs continuous batching on any arch.
+"""LM serving example: continuous batching on any arch.
 
-Runs the same mixed-length request trace twice — once through the
-run-to-completion baseline (``generate``), once through the continuous
-path (``submit`` + ``drain``: finished sequences leave the decode batch,
-freed KV slots are re-primed from fresh prefills) — and prints the
-per-request TTFT / tokens-per-second telemetry the engine stamps.
+Serves a mixed-length request trace through the engine (``submit`` +
+``drain``: finished sequences leave the decode batch, freed KV slots are
+re-primed from fresh prefills) and prints the per-request TTFT /
+tokens-per-second telemetry the engine stamps.
 
     PYTHONPATH=src python examples/serve_lm.py --arch deepseek-v2-236b
     PYTHONPATH=src python examples/serve_lm.py --arch rwkv6-7b --tokens 16
@@ -24,8 +23,8 @@ from repro.serving import Request, ServingEngine
 
 
 def make_requests(rng, cfg, n, tokens):
-    """Mixed budgets: every fourth request wants 2x the tokens, so a fixed
-    batch idles the short rows while continuous batching refills them."""
+    """Mixed budgets: every fourth request wants 2x the tokens, so short
+    rows leave the batch early and their slots are refilled."""
     return [Request(f"req-{i}",
                     rng.integers(1, cfg.vocab_size, 6 + i % 4).astype(np.int32),
                     max_new_tokens=tokens * 2 if i % 4 == 3 else tokens)
@@ -60,30 +59,17 @@ def main():
         return make_requests(np.random.default_rng(0), cfg, n_reqs,
                              args.tokens)
 
-    # fixed-batch baseline: arrival-order groups run to completion
-    fixed = ServingEngine(cfg, batch_size=args.batch, max_seq=96)
-    for i in range(0, n_reqs, args.batch):     # warmup: jit compiles
-        fixed.generate(trace()[i:i + args.batch])
-    reqs = trace()
-    steps0 = fixed.metrics["decode_steps"]
-    t0 = time.perf_counter()
-    for i in range(0, n_reqs, args.batch):
-        fixed.generate(reqs[i:i + args.batch])
-    report("fixed-batch", reqs, time.perf_counter() - t0, fixed, steps0)
-
-    # continuous batching: same trace, requests join/leave the batch per step
-    cont = ServingEngine(cfg, params=fixed.params,
-                         batch_size=args.batch, max_seq=96)
+    engine = ServingEngine(cfg, batch_size=args.batch, max_seq=96)
     for r in trace():                          # warmup: per-length prefills
-        cont.submit(r)
-    cont.drain()
+        engine.submit(r)
+    engine.drain()
     reqs = trace()
-    steps0 = cont.metrics["decode_steps"]
+    steps0 = engine.metrics["decode_steps"]
     t0 = time.perf_counter()
     for r in reqs:
-        cont.submit(r)
-    cont.drain()
-    report("continuous", reqs, time.perf_counter() - t0, cont, steps0)
+        engine.submit(r)
+    engine.drain()
+    report("continuous", reqs, time.perf_counter() - t0, engine, steps0)
 
 
 if __name__ == "__main__":

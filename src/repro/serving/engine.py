@@ -1,21 +1,15 @@
-"""LM serving engine: prefill + decode over the KV cache substrate.
+"""LM serving engine: continuous batching over the KV cache substrate.
 
-Two serving modes share the same jitted ``build_prefill_step`` /
-``build_decode_step`` functions the dry-run lowers for the 512-chip mesh,
-so what serves on one CPU device here is exactly what compiles for the pod:
+One decode loop serves every request: :meth:`ServingEngine.submit` puts a
+request on the waiting queue, and :meth:`ServingEngine.step` advances the
+shared decode batch one token.  Each batch slot owns an independent
+timeline: a freed slot is re-primed from a fresh B=1 prefill (the jitted
+``_prime``) and the per-row position vector keeps every other sequence
+exact in the jitted ``_decode``.  Requests join and leave the batch every
+step.  :meth:`ServingEngine.drain` steps until the engine is empty;
+:meth:`ServingEngine.serve_forever` is the driver thread's loop.
 
-- :meth:`ServingEngine.generate` — fixed-batch run-to-completion: one group
-  is left-padded to a common length, prefilled together, and decoded until
-  every member is done.  This is the measurable baseline continuous
-  batching is judged against.
-- continuous batching — :meth:`submit` puts a request on the waiting queue;
-  :meth:`step` advances the shared decode batch one token.  Each batch slot
-  owns an independent timeline: a freed slot is re-primed from a fresh B=1
-  prefill and the per-row position vector keeps every other sequence exact.
-  Requests join and leave the batch every step, which is what turns
-  mixed-length traffic from head-of-line blocking into goodput.
-
-KV storage comes in two layouts:
+KV storage comes in two layouts, decided by :func:`kv_pool_pages`:
 
 - **slot-granular** (default) — every batch slot owns a contiguous
   ``max_seq`` row of the decode cache, whether the request uses 9 tokens or
@@ -130,14 +124,24 @@ class _Slot:
     pages: List[int] = dataclasses.field(default_factory=list)
 
 
-class ServingEngine:
-    """Serving engine over a reduced config (CPU) or pod mesh (TPU).
+def kv_pool_pages(cfg, paged: bool, batch_size: int, max_seq: int,
+                  page_size: int, pool_pages: Optional[int]) -> int:
+    """Pages in the KV pool of an engine built with these arguments:
+    ``pool_pages`` as given, else enough for every row to hold ``max_seq``
+    tokens.  0 when paging is off or no cache leaf of ``cfg`` can page
+    (pure recurrent or ring stacks): every leaf is then slot-granular."""
+    if not (paged and paged_support(cfg)[0]):
+        return 0
+    if pool_pages is not None:
+        return pool_pages
+    return batch_size * -(-max_seq // page_size)
 
-    ``generate`` (fixed-batch) and the continuous path (``submit`` /
-    ``step`` / ``drain``) may be used on the same engine, but not
-    concurrently with each other — they share the jitted steps and metrics.
-    Continuous-path entry points are thread-safe; ``submit`` may be called
-    from many threads while a driver thread runs ``step``.
+
+class ServingEngine:
+    """Continuous-batching serving engine for one ``ArchConfig``.
+
+    ``submit`` may be called from many threads while one driver thread runs
+    ``step`` (``serve_forever``); every entry point is thread-safe.
 
     In paged mode ``max_seq`` is the per-request token cap (the page-table
     width); aggregate capacity is the page pool, not
@@ -171,41 +175,30 @@ class ServingEngine:
         self.params = params if params is not None else init_params(
             model_specs(cfg), seed)
         self._prefill = jax.jit(build_prefill_step(cfg))
-        self.paged = bool(paged)
         self.page_size = int(page_size)
-        self.pool_pages = 0
+        self.pool_pages = kv_pool_pages(cfg, paged, batch_size, max_seq,
+                                        self.page_size, pool_pages)
         self._pool: Optional[PagePool] = None
         self._prefix: Optional[PrefixCache] = None
         self._tables: Optional[np.ndarray] = None
-        if self.paged:
-            any_paged, prefix_ok = paged_support(cfg)
-            if any_paged:
-                self.max_pages = -(-max_seq // self.page_size)
-                self.pool_pages = (pool_pages if pool_pages is not None
-                                   else batch_size * self.max_pages)
-                self._flags = paged_cache_flags(cfg)
-                self._pool = PagePool(self.pool_pages, self.page_size)
-                self._tables = np.zeros((batch_size, self.max_pages),
-                                        np.int32)
-                self._tables_dev: Dict[int, object] = {}
-                self._decode = jax.jit(
-                    build_decode_step_paged(cfg, self.page_size),
-                    donate_argnums=1)
-                self._prime = jax.jit(self._prime_paged_fn, donate_argnums=2)
-                if prefix_sharing and prefix_ok:
-                    self._prefix = PrefixCache(self._pool)
-                    self._prefill_past = build_prefill_past_step(cfg)
-                    self._prime_past = jax.jit(self._prime_past_fn,
-                                               donate_argnums=2)
-            # archs with no pageable leaves (pure recurrent/ring stacks)
-            # fall through to the slot-granular path below
-        if self._pool is None:
+        if self.pool_pages:
+            self.max_pages = -(-max_seq // self.page_size)
+            self._flags = paged_cache_flags(cfg)
+            self._pool = PagePool(self.pool_pages, self.page_size)
+            self._tables = np.zeros((batch_size, self.max_pages), np.int32)
+            self._tables_dev: Dict[int, object] = {}
+            self._decode = jax.jit(
+                build_decode_step_paged(cfg, self.page_size),
+                donate_argnums=1)
+            self._prime = jax.jit(self._prime_paged_fn, donate_argnums=2)
+            if prefix_sharing and paged_support(cfg)[1]:
+                self._prefix = PrefixCache(self._pool)
+                self._prefill_past = build_prefill_past_step(cfg)
+                self._prime_past = jax.jit(self._prime_past_fn,
+                                           donate_argnums=2)
+        else:
             self._decode = jax.jit(build_decode_step(cfg), donate_argnums=1)
             self._prime = jax.jit(self._prime_fn, donate_argnums=2)
-        # fixed-batch ``generate`` always decodes contiguously (it owns a
-        # private cache and is the baseline the paged path is judged against)
-        self._decode_dense = (self._decode if self._pool is None else
-                              jax.jit(build_decode_step(cfg), donate_argnums=1))
         #: rows of resident state each decode step carries: every row on the
         #: slot-granular path, live or not; on the paged path every row if
         #: some leaf does not page (recurrent state, ring buffers), else none
@@ -282,72 +275,6 @@ class ServingEngine:
             if r.deadline_s is not None and r.finished_s > r.deadline_s:
                 r.expired = True
                 self.metrics["deadline_expired"] += 1
-
-    # -- fixed-batch baseline -------------------------------------------------
-    def generate(self, requests: List[Request]) -> List[Request]:
-        """Serve one group to completion (greedy decoding) — the fixed-batch
-        run-to-completion baseline.  Prompts are left-padded to the group's
-        longest; the batch decodes in lockstep until every member is done."""
-        if not requests:
-            return []
-        if len(requests) > self.batch_size:
-            raise AdmissionRefused(
-                ErrorCode.BAD_REQUEST,
-                f"bad request: group of {len(requests)} exceeds batch_size "
-                f"{self.batch_size}")
-        for r in requests:
-            self._validate(r)
-        B = self.batch_size
-        S = max(len(r.prompt) for r in requests)
-        max_new = max(r.max_new_tokens for r in requests)
-        if S + max_new > self.max_seq:
-            # padded group timeline: every member decodes from position S
-            raise AdmissionRefused(
-                ErrorCode.BAD_REQUEST,
-                f"kv cache overflow: padded prompt {S} + max_new_tokens "
-                f"{max_new} exceeds max_seq {self.max_seq}")
-        now = self.clock.monotonic()
-        for r in requests:
-            if r.arrived_s is None:
-                r.arrived_s = now
-        prompts = np.zeros((B, S), np.int32)
-        for i, r in enumerate(requests):
-            prompts[i, S - len(r.prompt):] = r.prompt     # left-pad
-        batch = {"tokens": jnp.asarray(prompts), **self._batch_extras(B)}
-
-        with span("engine.prime", self.metrics, "prefill_ms"):
-            prefill_cache, logits = self._prefill(self.params, batch)
-            logits = jax.block_until_ready(logits)
-
-        # decode continues in a max_seq cache primed from the prefill cache
-        cache = decode_cache(self.cfg, B, self.max_seq)
-        cache = extend_cache(cache, prefill_cache, S)
-        token = jnp.argmax(logits, axis=-1).astype(jnp.int32)[:, None]
-        # the prefill already predicts each sequence's next token: emit it
-        tok_np = np.asarray(token[:, 0])
-        for i, r in enumerate(requests):
-            self._emit(r, tok_np[i])
-        self.metrics["tokens"] += len(requests)
-        step = 0
-        while any(not r.done for r in requests):
-            pos = jnp.int32(S + step)
-            with span("engine.decode", self.metrics, "decode_ms"):
-                cache, logits = self._decode_dense(self.params, cache, token,
-                                                   pos)
-                logits = jax.block_until_ready(logits)
-            self.metrics["decode_steps"] += 1
-            token = jnp.argmax(logits, axis=-1).astype(jnp.int32)[:, None]
-            tok_np = np.asarray(token[:, 0])
-            emitted = 0
-            for i, r in enumerate(requests):
-                if not r.done:
-                    self._emit(r, tok_np[i])
-                    emitted += 1
-            # only still-generating rows are billable work
-            self.metrics["tokens"] += emitted
-            step += 1
-        self.metrics["requests"] += len(requests)
-        return requests
 
     # -- continuous batching --------------------------------------------------
     def submit(self, r: Request) -> Request:

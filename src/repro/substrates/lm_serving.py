@@ -50,9 +50,8 @@ from repro.core.errors import AdmissionRefused, ErrorCode
 from repro.core.simclock import SYSTEM_CLOCK, Clock
 from repro.core.telemetry import RuntimeSnapshot
 from repro.core.twin import TwinNotReady, TwinState, TwinSurrogate
-from repro.models import paged_support
 from repro.roofline.serving import ServingCostModel
-from repro.serving.engine import Request, ServingEngine
+from repro.serving.engine import Request, ServingEngine, kv_pool_pages
 from repro.substrates.base import SubstrateAdapter
 
 #: generous hard cap on how long one invoke may wait for its tokens (the
@@ -141,15 +140,13 @@ class LmServingAdapter(SubstrateAdapter):
         self.max_concurrent = max_concurrent
         self.calibrate = calibrate
         self.clock = clock if clock is not None else SYSTEM_CLOCK
-        self.paged = paged
         self.page_size = page_size
-        self.pool_pages = pool_pages
+        #: pages of the engine's kv pool, 0 when every leaf is slot-granular
+        self.pool_pages = kv_pool_pages(cfg, paged, batch_size, max_seq,
+                                        page_size, pool_pages)
         self.prefix_sharing = prefix_sharing
         kw = {} if safety is None else {"safety": safety}
-        if paged and paged_support(self.cfg)[0]:
-            max_pages = -(-max_seq // page_size)
-            self.pool_pages = (pool_pages if pool_pages is not None
-                               else batch_size * max_pages)
+        if self.pool_pages:
             kw.update(page_size=page_size, pool_pages=self.pool_pages)
         self.cost = ServingCostModel(self.cfg, batch_size=batch_size,
                                      max_seq=max_seq, **kw)
@@ -193,7 +190,7 @@ class LmServingAdapter(SubstrateAdapter):
             supports_repeated_invocation=True,
         )
         kv = (f"paged kv pool={self.pool_pages}x{self.page_size}tok"
-              if self.paged and self.pool_pages else "slot-granular kv")
+              if self.pool_pages else "slot-granular kv")
         return ResourceDescriptor(
             resource_id=self.resource_id, substrate_class="lm_serving",
             adapter_type="in_process", location="cloud",
@@ -239,7 +236,8 @@ class LmServingAdapter(SubstrateAdapter):
         engine = ServingEngine(self.cfg, self.params,
                                batch_size=self.batch_size,
                                max_seq=self.max_seq, seed=self.seed,
-                               paged=self.paged, page_size=self.page_size,
+                               paged=self.pool_pages > 0,
+                               page_size=self.page_size,
                                pool_pages=self.pool_pages,
                                prefix_sharing=self.prefix_sharing,
                                clock=self.clock)

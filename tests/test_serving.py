@@ -1,9 +1,10 @@
 """Serving-path tests: engine correctness, continuous batching, admission.
 
-Covers the serving satellite set: empty/partial batches, mixed prompt
-lengths and max_new_tokens, token-metric exactness, batched-vs-single
-greedy-decode parity — plus the continuous-batching loop (slot reuse,
-per-row timelines) and the LM serving adapter's structured refusals.
+Covers an idle drain, a lone request in a wide batch, token-metric
+exactness, the structured refusals of malformed requests, parity of the
+continuous-batching loop (slot reuse, per-row timelines) with a plain
+greedy decode of each request alone, the KV layout decision, and the LM
+serving adapter's structured refusals.
 """
 import threading
 
@@ -13,6 +14,8 @@ import pytest
 from repro.configs import get_config, reduced
 from repro.core.errors import AdmissionRefused, ErrorCode
 from repro.serving import Request, ServingEngine
+from repro.serving.engine import kv_pool_pages
+from tests.greedy_reference import greedy_reference
 
 ARCH = "internlm2-20b"
 MAX_SEQ = 64
@@ -40,77 +43,44 @@ def make_prompt(rng, cfg, n):
     return rng.integers(1, cfg.vocab_size, size=n).astype(np.int32)
 
 
-# -- fixed-batch generate() ---------------------------------------------------
+# -- submit / drain edge cases -----------------------------------------------
 
-def test_generate_empty_group_returns_empty(cfg, params):
+def test_drain_with_nothing_submitted_emits_nothing(cfg, params):
     eng = make_engine(cfg, params)
-    assert eng.generate([]) == []
+    eng.drain()
     assert eng.metrics["tokens"] == 0
+    assert eng.metrics["decode_steps"] == 0
 
 
-def test_generate_partial_batch(cfg, params):
+def test_single_request_in_wide_batch_done_exact(cfg, params):
+    """One request in a 4-row engine: done flips at exactly max_new_tokens
+    (never an over-append), N tokens take N-1 decode steps (the first comes
+    from the prime), and only the live row's tokens are counted."""
     rng = np.random.default_rng(0)
     eng = make_engine(cfg, params, batch_size=4)
-    reqs = [Request("a", make_prompt(rng, cfg, 6), max_new_tokens=3)]
-    out = eng.generate(reqs)
-    assert len(out) == 1 and out[0].done
-    assert len(out[0].generated) == 3
+    r = eng.submit(Request("a", make_prompt(rng, cfg, 6), max_new_tokens=5))
+    eng.drain()
+    assert r.done and len(r.generated) == 5
+    assert eng.metrics["decode_steps"] == 4
+    assert eng.metrics["tokens"] == 5
 
 
-def test_generate_mixed_lengths_done_exact(cfg, params):
-    rng = np.random.default_rng(1)
-    eng = make_engine(cfg, params, batch_size=3)
-    reqs = [Request("a", make_prompt(rng, cfg, 5), max_new_tokens=2),
-            Request("b", make_prompt(rng, cfg, 8), max_new_tokens=7),
-            Request("c", make_prompt(rng, cfg, 6), max_new_tokens=4)]
-    out = eng.generate(reqs)
-    # done flips at exactly max_new_tokens — never an over-append
-    for r in out:
-        assert r.done and len(r.generated) == r.max_new_tokens
-    # early exit: N tokens need N-1 decode steps (first token from prefill)
-    assert eng.metrics["decode_steps"] == max(r.max_new_tokens
-                                              for r in reqs) - 1
-
-
-def test_generate_token_metric_counts_only_live_rows(cfg, params):
-    rng = np.random.default_rng(2)
-    eng = make_engine(cfg, params, batch_size=3)
-    reqs = [Request("a", make_prompt(rng, cfg, 6), max_new_tokens=2),
-            Request("b", make_prompt(rng, cfg, 6), max_new_tokens=9)]
-    eng.generate(reqs)
-    # exactly the tokens delivered — not len(requests) x steps
-    assert eng.metrics["tokens"] == sum(r.max_new_tokens for r in reqs)
-
-
-def test_generate_batched_vs_single_parity(cfg, params):
-    """Equal-length prompts batched together decode exactly as alone."""
-    rng = np.random.default_rng(3)
-    prompts = [make_prompt(rng, cfg, 7) for _ in range(3)]
-    eng = make_engine(cfg, params, batch_size=3)
-    batched = eng.generate([Request(f"b{i}", p, max_new_tokens=5)
-                            for i, p in enumerate(prompts)])
-    for i, p in enumerate(prompts):
-        solo = make_engine(cfg, params, batch_size=1)
-        [ref] = solo.generate([Request("s", p, max_new_tokens=5)])
-        assert ref.generated == batched[i].generated
-
-
-def test_generate_structured_refusals(cfg, params):
+@pytest.mark.parametrize("prompt_len,max_new,message", [
+    (40, 1, "prompt length 40 exceeds max_seq 32"),
+    (0, 1, "empty prompt"),
+    (30, 10, "kv cache overflow: prompt 30 + max_new_tokens 10 exceeds "
+             "max_seq 32"),
+    (4, 0, "bad request: max_new_tokens 0 < 1"),
+], ids=["over_max_seq", "empty", "cache_overflow", "no_new_tokens"])
+def test_submit_refuses_malformed_request(cfg, params, prompt_len, max_new,
+                                          message):
     eng = make_engine(cfg, params, batch_size=2, max_seq=32)
     with pytest.raises(AdmissionRefused) as ei:
-        eng.generate([Request("long", np.ones(40, np.int32))])
+        eng.submit(Request("bad", np.ones(prompt_len, np.int32),
+                           max_new_tokens=max_new))
     assert ei.value.code == ErrorCode.BAD_REQUEST
-    assert "exceeds max_seq" in str(ei.value)
-    with pytest.raises(AdmissionRefused):
-        eng.generate([Request("empty", np.zeros(0, np.int32))])
-    with pytest.raises(AdmissionRefused) as ei:
-        # prompt fits but prompt + max_new overflows the cache
-        eng.generate([Request("ovf", np.ones(30, np.int32),
-                              max_new_tokens=10)])
-    assert "kv cache overflow" in str(ei.value)
-    with pytest.raises(AdmissionRefused):
-        eng.generate([Request(f"x{i}", np.ones(4, np.int32))
-                      for i in range(3)])   # group > batch_size
+    assert message in str(ei.value)
+    assert eng.backlog_tokens() == 0        # refusal touches no engine state
 
 
 # -- continuous batching ------------------------------------------------------
@@ -130,10 +100,8 @@ def test_continuous_matches_single_runs_mixed_lengths(cfg, params):
     assert all(r.done and len(r.generated) == r.max_new_tokens for r in reqs)
     assert eng.metrics["tokens"] == sum(mn for _, mn in shapes)
     for r in reqs:
-        solo = make_engine(cfg, params, batch_size=1)
-        [ref] = solo.generate([Request("s", r.prompt,
-                                       max_new_tokens=r.max_new_tokens)])
-        assert ref.generated == r.generated, r.request_id
+        assert r.generated == greedy_reference(
+            cfg, params, r.prompt, r.max_new_tokens, MAX_SEQ), r.request_id
 
 
 def test_continuous_telemetry_stamps(cfg, params):
@@ -199,11 +167,22 @@ def test_continuous_parity_ring_buffer_and_recurrent_state():
         eng.submit(r)
     eng.drain()
     for r in reqs:
-        solo = ServingEngine(cfg, params=params, batch_size=1,
-                             max_seq=MAX_SEQ)
-        [ref] = solo.generate([Request("s", r.prompt,
-                                       max_new_tokens=r.max_new_tokens)])
-        assert ref.generated == r.generated, r.request_id
+        assert r.generated == greedy_reference(
+            cfg, params, r.prompt, r.max_new_tokens, MAX_SEQ), r.request_id
+
+
+# -- kv layout ----------------------------------------------------------------
+
+@pytest.mark.parametrize("arch,paged,pool_pages,want", [
+    (ARCH, False, None, 0),
+    ("rwkv6-7b", True, None, 0),            # no cache leaf can page
+    (ARCH, True, None, 4 * 5),              # 4 rows x ceil(70 / 16) pages
+    (ARCH, True, 48, 48),
+], ids=["paging_off", "nothing_pageable", "rows_x_max_pages", "explicit"])
+def test_kv_pool_pages(arch, paged, pool_pages, want):
+    cfg = reduced(get_config(arch))
+    assert kv_pool_pages(cfg, paged, batch_size=4, max_seq=70, page_size=16,
+                         pool_pages=pool_pages) == want
 
 
 # -- control-plane adapter ----------------------------------------------------
@@ -273,3 +252,24 @@ def test_adapter_descriptor_and_twin(serving_orchestrator):
     sim = twin.surrogate.simulate(_task("sim-1"))
     assert sim["output"]["predicted"] is True
     assert sim["telemetry"]["step_ms"] > 0.0
+
+
+@pytest.mark.parametrize("arch,paged", [(ARCH, True), (ARCH, False),
+                                        ("rwkv6-7b", True)],
+                         ids=["paged", "slot", "nothing_pageable"])
+def test_adapter_prices_the_pool_its_engine_holds(arch, paged):
+    from repro.substrates import LmServingAdapter
+
+    adapter = LmServingAdapter(reduced(get_config(arch)), batch_size=2,
+                               max_seq=MAX_SEQ, paged=paged, page_size=8,
+                               calibrate=False)
+    adapter.prepare(None)
+    try:
+        pages = adapter.engine.pool_pages
+        assert (adapter.cost.pool_pages or 0) == adapter.pool_pages == pages
+        assert adapter.engine.pool_stats().get("pool_pages", 0) == pages
+        assert ("paged kv pool=" in adapter.descriptor().description) \
+            == (pages > 0)
+    finally:
+        adapter.close()
+    assert pages == (2 * MAX_SEQ // 8 if arch == ARCH and paged else 0)

@@ -25,6 +25,7 @@ from repro.models.common import init_params
 from repro.models.transformer import Stack, apply_layer_decode
 from repro.roofline.serving import ServingCostModel
 from repro.serving import Request, ServingEngine
+from tests.greedy_reference import greedy_reference
 
 #: one arch per cache family: pure global-attention KV, MLA latent KV, and
 #: a recurrent/ring hybrid with no pageable leaves at all
@@ -181,10 +182,8 @@ def test_request_longer_than_slot_granular_cap_completes(attn):
     r = paged.submit(Request("long", prompt, max_new_tokens=9))
     paged.drain()
     assert r.done and len(r.generated) == 9
-    # reference: the same request on a contiguous 64-token engine
-    ref = ServingEngine(cfg, params=params, batch_size=1, max_seq=64)
-    [ref_r] = ref.generate([Request("ref", prompt, max_new_tokens=9)])
-    assert r.generated == ref_r.generated
+    # reference: the same request greedily decoded over a contiguous cache
+    assert r.generated == greedy_reference(cfg, params, prompt, 9, 64)
     assert paged.audit_pages()["used"] == 0
 
 
