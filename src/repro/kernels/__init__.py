@@ -1,4 +1,5 @@
-"""Pallas TPU kernels (flash attention, RG-LRU scan, RWKV-6 scan).
+"""Pallas TPU kernels (flash attention, paged decode attention, RG-LRU scan,
+RWKV-6 scan).
 
 Each kernel takes ``interpret`` with no default.  The model path asks
 :func:`interpret_mode` — the one place that decides it — so a kernel runs

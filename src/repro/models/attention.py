@@ -284,14 +284,18 @@ def paged_decode_attention(cfg, p: dict, x, cache: dict, pos, tables, *,
     cache k/v: (num_pages+1, page_size, K, hd) — row 0 is the null page
     that dead batch rows write into and no one reads.  With ``layer``, k/v
     are the whole layer-stacked pool (reps, num_pages+1, page_size, K, hd)
-    and only layer ``layer`` is written and read: one scatter and one
-    gather on the stack, so a scan carrying it updates it in place.
+    and only layer ``layer`` is written and read: one scatter on the stack,
+    so a scan carrying it updates it in place.
     tables: (B, max_pages) int32 page ids (0 where unallocated) — the
     per-row page-index vectors generalizing the per-row position vectors.
     pos: (B,) per-row absolute positions.  The engine guarantees every
     position <= pos[b] is backed by a real page in row b's table, and that
     the write page (block ``pos // page_size``) is private to row b —
     shared prefix pages are immutable by construction.
+
+    With ``cfg.use_pallas`` and a pool the kernel reads, the Pallas paged
+    decode kernel attends, reading each row's own pages in place; otherwise
+    :func:`paged_gather_attention`, its reference.
     """
     q, k_new, v_new = project_qkv(p, x)           # (B, 1, ., .)
     pos = jnp.asarray(pos, jnp.int32)
@@ -307,18 +311,36 @@ def paged_decode_attention(cfg, p: dict, x, cache: dict, pos, tables, *,
     at = () if layer is None else (layer,)
     k_pool = k_pool.at[at + (pid, off)].set(k_new[:, 0].astype(k_pool.dtype))
     v_pool = v_pool.at[at + (pid, off)].set(v_new[:, 0].astype(v_pool.dtype))
+    from repro.kernels import interpret_mode, paged_decode as kernel
+
+    K, hd = k_pool.shape[-2], k_pool.shape[-1]
+    if cfg.use_pallas and kernel.supported(k_pool.dtype, K, hd):
+        o = kernel.paged_decode(q[:, 0], k_pool, v_pool, pos + 1, tables,
+                                0 if layer is None else layer,
+                                interpret=interpret_mode())[:, None]
+    else:
+        o = paged_gather_attention(q, k_pool, v_pool, pos, tables,
+                                   page_size=page_size, layer=layer)
+    return out_proj(p, o), {"k": k_pool, "v": v_pool}
+
+
+def paged_gather_attention(q, k_pool, v_pool, pos, tables, *, page_size: int,
+                           layer=None):
+    """q: (B, 1, H, hd) at positions ``pos`` against the pool through the
+    tables: every row's table gathered into a contiguous (B, T, K, hd) copy
+    at the table's width, then attended under a mask."""
+    B, _, H, _ = q.shape
+    at = () if layer is None else (layer,)
     K, hd = k_pool.shape[-2], k_pool.shape[-1]
     T = tables.shape[1] * page_size
     k = k_pool[at + (tables,)].reshape(B, T, K, hd)   # gather through the table
     v = v_pool[at + (tables,)].reshape(B, T, K, hd)
     idx = jnp.arange(T, dtype=jnp.int32)
     kv_valid = idx[None, :] <= pos[:, None]
-    H = q.shape[2]
     qg = q.reshape(B, 1, K, H // K, hd)
-    o = _block_attend(qg, k, v, posv, idx, causal=True, window=None,
+    o = _block_attend(qg, k, v, pos[:, None], idx, causal=True, window=None,
                       kv_valid=kv_valid)
-    o = o.reshape(B, 1, H, hd)
-    return out_proj(p, o), {"k": k_pool, "v": v_pool}
+    return o.reshape(B, 1, H, hd)
 
 
 def cross_attention(cfg, p: dict, x, kv_cache: dict):

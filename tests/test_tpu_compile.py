@@ -119,11 +119,10 @@ def _assert_fits(compiled):
                                    ma.temp_size_in_bytes)
 
 
-def _compile_decode_step(one_chip, paged):
+def _compile_decode_step(one_chip, paged, cfg=ONE_CHIP):
     from repro.models import (build_decode_step, build_decode_step_paged,
                               decode_cache, decode_cache_paged)
 
-    cfg = ONE_CHIP
     params = _params(cfg, one_chip)
     tok = _sd(one_chip, (BATCH, 1), jnp.int32)
     pos = _sd(one_chip, (BATCH,), jnp.int32)
@@ -141,9 +140,34 @@ def _compile_decode_step(one_chip, paged):
 
 
 @pytest.mark.parametrize("paged", [False, True], ids=["slot", "paged"])
-def test_one_chip_decode_step_compiles_and_fits(one_chip, paged):
+def test_one_chip_decode_step_compiles_and_fits(one_chip, compiled_kernels,
+                                                paged):
     compiled, _ = _compile_decode_step(one_chip, paged)
     _assert_fits(compiled)
+
+
+def test_one_chip_paged_decode_runs_the_paged_kernel(one_chip,
+                                                     compiled_kernels):
+    """The paged step attends through the paged decode kernel, which reads
+    each row's pages from the stacked pool in place: no (B, T, K, hd) copy
+    of a layer's tables is gathered, and the step's temporaries stay below
+    the gather path's and below one such copy."""
+    compiled, cache = _compile_decode_step(one_chip, paged=True)
+    text = compiled.as_text()
+    assert re.search(r"%paged_decode\S* = \S+ custom-call\(", text)
+    K, hd = cache["blocks"]["0"]["k"].shape[-2:]
+    copy_shapes = {f"bf16[{BATCH},{MAX_SEQ},{K},{hd}]",
+                   f"bf16[{BATCH * MAX_SEQ // PAGE},{PAGE},{K},{hd}]"}
+    gathers = [m.group(0) for m in re.finditer(r"= (bf16\[[\d,]*\])\S* \w+",
+                                               text)
+               if m.group(1) in copy_shapes]
+    assert not gathers, gathers[:4]
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    gather_twin, _ = _compile_decode_step(
+        one_chip, paged=True, cfg=dataclasses.replace(ONE_CHIP,
+                                                      use_pallas=False))
+    assert temp <= gather_twin.memory_analysis().temp_size_in_bytes
+    assert temp < BATCH * MAX_SEQ * K * hd * 2, temp
 
 
 @pytest.fixture(scope="module")
@@ -199,9 +223,10 @@ def test_rwkv6_one_chip_decode_step_updates_the_state_in_place(rwkv6_decode):
     assert temp < layer_bytes, (temp, layer_bytes)
 
 
-def test_one_chip_paged_decode_updates_the_pool_in_place(one_chip):
+def test_one_chip_paged_decode_updates_the_pool_in_place(one_chip,
+                                                        compiled_kernels):
     """The stacked KV pool rides in the layer scan's carry: each step
-    scatters its new rows into it and gathers through the tables, and never
+    scatters its new rows into it and reads it through the tables, and never
     copies, slices out or writes back a layer's pool or the whole stack."""
     compiled, cache = _compile_decode_step(one_chip, paged=True)
     stack = cache["blocks"]["0"]["k"].shape       # (layers, pages+1, ...)
